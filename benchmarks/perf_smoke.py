@@ -26,6 +26,7 @@ import sys
 import time
 
 from repro.core.hext import programs
+from repro.core.hext.engine import use_compile_cache
 from repro.core.hext.sim import Fleet
 
 GOLDEN_PATH = "benchmarks/results/hext_runs.json"
@@ -90,6 +91,7 @@ def main(out_path: str = GOLDEN_PATH) -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=GOLDEN_PATH)
     sys.exit(main(ap.parse_args().out))

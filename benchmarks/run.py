@@ -162,4 +162,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    from repro.core.hext.engine import use_compile_cache
+    use_compile_cache()
     main()

@@ -193,6 +193,7 @@ def main(out_path: str = "benchmarks/results/hext_runs.json",
 
 
 if __name__ == "__main__":
+    hext_engine.use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="benchmarks/results/hext_runs.json")
     ap.add_argument("--max-ticks", type=int, default=120000)

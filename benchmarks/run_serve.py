@@ -36,6 +36,7 @@ import time
 import numpy as np
 
 from repro.core.hext import programs
+from repro.core.hext.engine import use_compile_cache
 from repro.core.hext.policies import BinPackPolicy
 from repro.core.hext.service import DONE, FleetService
 
@@ -150,7 +151,7 @@ def run_smoke(args) -> dict:
     return report
 
 
-def main(argv=None) -> int:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default=os.path.join(
         os.path.dirname(__file__), "results", "serve_runs.json"))
@@ -171,8 +172,11 @@ def main(argv=None) -> int:
                     help="slice at which the failure is injected")
     ap.add_argument("--smoke", action="store_true",
                     help="run the fixed 16-submission CI gate instead")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
     report = run_smoke(args) if args.smoke else run_soak(args)
     report["generated_by"] = "benchmarks/run_serve.py"
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
@@ -191,4 +195,5 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     raise SystemExit(main())

@@ -27,6 +27,7 @@ import os
 import time
 
 from repro.core.hext import torture
+from repro.core.hext.engine import use_compile_cache
 from repro.core.hext.sim import Fleet
 
 
@@ -117,6 +118,7 @@ def main(out_path: str = "benchmarks/results/torture_fuzz.json",
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="benchmarks/results/torture_fuzz.json")
     ap.add_argument("--seed", type=int, default=torture.DEFAULT_SEED)

@@ -15,6 +15,7 @@ import tempfile
 import time
 
 from repro.core.hext import programs
+from repro.core.hext.engine import use_compile_cache
 from repro.core.hext.sim import Fleet, MigrationError
 
 
@@ -98,4 +99,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

@@ -15,7 +15,7 @@ import sys
 import time
 
 from repro.core.hext import programs
-from repro.core.hext.engine import ENGINES
+from repro.core.hext.engine import ENGINES, use_compile_cache
 from repro.core.hext.sim import Fleet
 
 
@@ -46,4 +46,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     main()

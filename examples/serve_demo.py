@@ -12,6 +12,7 @@ Run:
     PYTHONPATH=src python examples/serve_demo.py
 """
 from repro.core.hext import programs
+from repro.core.hext.engine import use_compile_cache
 from repro.core.hext.policies import BinPackPolicy
 from repro.core.hext.service import FleetService
 
@@ -63,4 +64,5 @@ def main():
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     raise SystemExit(main())

@@ -6,17 +6,26 @@ across ``isa.py`` / ``machine.py`` / ``translate.py`` / ``tlb.py``
 (each module had its own ``_u``).  Everything is branchless jnp so it
 traces into fixed graphs and vmaps over harts.
 
-64-bit integer semantics require x64 mode; call sites own the
-``jax.experimental.enable_x64()`` context (the sim facade and engines
-do this in one place).
+64-bit integer semantics require x64 mode; call sites run under the
+scoped :func:`x64` context (the sim facade and engines do this at their
+entry points).
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 U64 = jnp.uint64
 I64 = jnp.int64
 MASK64 = (1 << 64) - 1
+
+
+def x64():
+    """The one x64 context of the hext core: ``with x64(): ...``.
+
+    Scoped, not process-wide, so code outside it (the LLM stack sharing
+    the process) keeps JAX's default 32-bit dtypes."""
+    return jax.enable_x64(True)
 
 
 def u64(x) -> jnp.ndarray:

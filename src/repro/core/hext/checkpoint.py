@@ -30,12 +30,12 @@ import os
 import tempfile
 from typing import Any, Dict, List, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.hext import machine as _machine
 from repro.core.hext import programs as _programs
+from repro.core.hext.bits import x64
 
 FORMAT = "hext-fleet-checkpoint"
 VERSION = 1
@@ -53,10 +53,6 @@ class CheckpointError(RuntimeError):
     """A checkpoint file is unreadable, corrupted, or schema-incompatible."""
 
 
-def _x64():
-    return jax.experimental.enable_x64()
-
-
 _STATE_KEYS = ("pc", "regs", "csrs", "priv", "virt", "mem", "halted",
                "console")
 _COUNTER_KEYS = ("done", "exit_code", "instret", "instret_virt",
@@ -65,7 +61,7 @@ _COUNTER_KEYS = ("done", "exit_code", "instret", "instret_virt",
 
 
 def _flatten(harts) -> Dict[str, np.ndarray]:
-    with _x64():
+    with x64():
         out = {k: np.asarray(getattr(harts, k)) for k in _STATE_KEYS}
         out.update({f"tlb.{k}": np.asarray(v)
                     for k, v in harts.tlb.items()})
@@ -77,7 +73,7 @@ def _flatten(harts) -> Dict[str, np.ndarray]:
 def _expected_keys_and_dtypes() -> Dict[str, np.dtype]:
     """What the *current* HartState layout looks like (tiny reference
     state) — the restore side's notion of a compatible schema."""
-    with _x64():
+    with x64():
         ref = _machine._make_state(1)
     out = {k: np.asarray(ref[k]).dtype for k in _STATE_KEYS}
     out.update({f"tlb.{k}": np.asarray(v).dtype
@@ -362,7 +358,7 @@ def load_guest(path: str) -> Tuple[Dict[str, np.ndarray], Dict[str, Any]]:
 
 def _to_harts(arrays: Dict[str, np.ndarray]):
     from repro.core.hext.sim import Counters, HartState
-    with _x64():
+    with x64():
         j = {k: jnp.asarray(v) for k, v in arrays.items()}
         counters = Counters(**{k: j[f"counters.{k}"]
                                for k in _COUNTER_KEYS})

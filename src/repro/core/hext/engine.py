@@ -33,6 +33,8 @@ All entry points own the x64 context, like the facade they serve.
 from __future__ import annotations
 
 import dataclasses
+import os
+import pathlib
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Protocol, \
     runtime_checkable
@@ -44,6 +46,7 @@ import numpy as np
 from repro.core.hext import csr as C
 from repro.core.hext import machine as _machine
 from repro.core.hext import oracle as _oracle
+from repro.core.hext.bits import x64
 
 U64 = jnp.uint64
 MASK64 = (1 << 64) - 1
@@ -51,7 +54,7 @@ MASK64 = (1 << 64) - 1
 __all__ = ["Engine", "JitEngine", "ShardedEngine", "OracleEngine",
            "ENGINES", "register_engine", "resolve", "diff_states",
            "diff_arrays", "state_arrays", "DIFF_SCALARS",
-           "DIFF_COUNTERS"]
+           "DIFF_COUNTERS", "use_compile_cache"]
 
 # The single definition of the differential comparison scope, shared by
 # `diff_states` and the torture harness's array-based diff so the two
@@ -63,8 +66,20 @@ DIFF_COUNTERS = ("instret", "instret_virt", "pagefaults", "walks",
                  "ticks", "timer_irqs", "ctx_switches")
 
 
-def _x64():
-    return jax.experimental.enable_x64()
+def use_compile_cache() -> str:
+    """Give JAX's persistent compile cache a fixed home; returns its path.
+
+    Entry points (benchmarks, examples, the torture CLI, ``chip_smoke.py``)
+    call this at start-up; importing ``repro`` sets nothing.  A cache placed
+    from outside through ``JAX_COMPILATION_CACHE_DIR`` is left where it is.
+    Otherwise it goes to ``<checkout>/.jax_cache``, a fixed path, so a later
+    run in the same checkout finds what an earlier one compiled."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    path = str(pathlib.Path(__file__).resolve().parents[4] / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _n_chunks(max_ticks: int, chunk: int) -> int:
@@ -202,10 +217,12 @@ class JitEngine:
     def run(self, state, max_ticks: int, chunk: int = 4096):
         ips = _check_ips(chunk, self._ips)
         fn = _run_jit_donating if self._donate else _run_jit
-        with _x64(), warnings.catch_warnings():
-            # buffer donation is best-effort on some backends (e.g. CPU)
-            warnings.filterwarnings(
-                "ignore", message=".*[Dd]onat.*", category=UserWarning)
+        with x64(), warnings.catch_warnings():
+            # the CPU backend may decline donation; anywhere else a declined
+            # donation copies all hart state every run, so it stays loud
+            if jax.default_backend() == "cpu":
+                warnings.filterwarnings(
+                    "ignore", message=".*[Dd]onat.*", category=UserWarning)
             out = fn(state, jnp.asarray(_n_chunks(max_ticks, chunk),
                                         jnp.int32), int(chunk), ips)
             return jax.block_until_ready(out)
@@ -255,7 +272,7 @@ class ShardedEngine:
         if not _is_batched(state) or len(devs) < 2:
             return JitEngine(instrs_per_step=ips).run(state, max_ticks,
                                                       chunk)
-        with _x64():
+        with x64():
             b = int(state.counters.done.shape[0])
             d = min(len(devs), b)
             bp = -(-b // d) * d
@@ -382,7 +399,7 @@ class OracleEngine:
     def run(self, state, max_ticks: int, chunk: int = 4096):
         total = _n_chunks(max_ticks, chunk) * int(chunk)
         self.last_events = []
-        with _x64():
+        with x64():
             if not _is_batched(state):
                 return self._run_row(state, total)
             rows = [jax.tree.map(lambda x, i=i: x[i], state)
@@ -414,7 +431,7 @@ def state_arrays(state) -> Dict[str, np.ndarray]:
     """Host-array extraction of a (scalar or batched) ``HartState``,
     shaped for :func:`diff_arrays` — one batched device→host copy per
     field, leading batch dim always present."""
-    with _x64():
+    with x64():
         batched = _is_batched(state)
 
         def arr(x):

@@ -39,8 +39,8 @@ bit.  Comparators boot disarmed (2^64-1), so workloads that never arm
 one see identical behavior.
 
 64-bit integer state requires x64; call sites must run under
-``with jax.experimental.enable_x64():`` — ``run``/``batched_run`` do this
-internally around trace+execute.
+``with bits.x64():`` — ``run``/``batched_run`` do this internally around
+trace+execute.
 
 NOTE: this module is the raw-dict ISA-core layer.  The public simulation
 API is ``repro.core.hext.sim`` (typed ``HartState`` pytree + ``Fleet``
@@ -61,6 +61,7 @@ from repro.core.hext import tlb as TLB
 from repro.core.hext import translate as X
 from repro.core.hext import trap as TR
 from repro.core.hext.bits import u64 as _u
+from repro.core.hext.bits import x64
 
 U64 = jnp.uint64
 
@@ -98,7 +99,7 @@ def _make_state(mem_words: int) -> Dict:
 
 def load_image(state: Dict, image, base: int = 0) -> Dict:
     """Write a uint64-word image into memory at byte address `base`."""
-    with jax.experimental.enable_x64():
+    with x64():
         w = base >> 3
         mem = state["mem"].at[w:w + image.shape[0]].set(image.astype(U64))
         return {**state, "mem": mem}
@@ -395,7 +396,7 @@ def step(state: Dict) -> Dict:
 
 def run(state: Dict, n_ticks: int, unroll: int = 1) -> Dict:
     """Scan `n_ticks` steps (compiled once)."""
-    with jax.experimental.enable_x64():
+    with x64():
         def body(s, _):
             return step_batched(s), None
         fn = jax.jit(lambda s: jax.lax.scan(body, s, None, length=n_ticks,
@@ -408,7 +409,7 @@ def batched_run(states: Dict, n_ticks: int) -> Dict:
     """Run a hart batch — many VMs simulated in lockstep.  Scans the
     batched pipeline directly (batch-level conds stay real conditionals;
     a vmap-of-scalar-step would compute both branches everywhere)."""
-    with jax.experimental.enable_x64():
+    with x64():
         def body(s, _):
             return step_batched(s), None
         return jax.jit(lambda s: jax.lax.scan(body, s, None,

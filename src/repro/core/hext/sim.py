@@ -29,8 +29,8 @@ gem5-style checkpointing (``Fleet.snapshot`` / ``Fleet.restore``, a
 versioned ``.npz`` with a schema-hash guard — see
 :mod:`repro.core.hext.checkpoint`) and live guest migration between harts
 (``Fleet.migrate_guest``).  The x64 requirement is owned by the facade and
-the engines in one place instead of being sprinkled across per-call
-wrappers.
+the engines, which enter the one scoped ``bits.x64()`` context at their
+entry points instead of sprinkling per-call wrappers.
 """
 from __future__ import annotations
 
@@ -44,17 +44,13 @@ import numpy as np
 
 from repro.core.hext import engine as _engine
 from repro.core.hext import machine as _machine
+from repro.core.hext.bits import x64
 
 U64 = jnp.uint64
 MASK64 = (1 << 64) - 1
 
 __all__ = ["Counters", "HartState", "Fleet", "HartSpec", "checksum_ok",
            "run_on_device", "StaleHartsError", "MigrationError"]
-
-
-def _x64():
-    """The one x64 context the facade owns (64-bit architectural state)."""
-    return jax.experimental.enable_x64()
 
 
 def checksum_ok(exit_code, golden: int) -> bool:
@@ -123,7 +119,7 @@ class Counters:
 
     def to_dict(self, golden: Optional[int] = None) -> Dict[str, Any]:
         """Host-side dict (JSON-safe) — the legacy benchmark record shape."""
-        with _x64():
+        with x64():
             out = {
                 "done": bool(self.done),
                 # masked to uint64 so a report entry can reproduce the
@@ -182,7 +178,7 @@ class HartState:
     @classmethod
     def fresh(cls, mem_words: int = _machine.DEFAULT_MEM_WORDS) -> "HartState":
         """Power-on state: pc=0, M mode, zeroed memory and counters."""
-        with _x64():
+        with x64():
             return cls.from_raw(_machine._make_state(mem_words))
 
     @classmethod
@@ -191,7 +187,7 @@ class HartState:
         (native M→S stack, or M→HS xvisor-lite→VS when ``guest``)."""
         from repro.core.hext import programs
         image = programs.build_image(workload, guest)
-        with _x64():
+        with x64():
             st = cls.fresh(programs.MEM_WORDS)
             return st.with_mem(jnp.asarray(image))
 
@@ -206,7 +202,7 @@ class HartState:
         ts = programs.DEFAULT_TIMESLICE if timeslice is None else \
             int(timeslice)
         image = programs.build_image_nguest(workloads, timeslice=ts)
-        with _x64():
+        with x64():
             st = cls.fresh(int(image.shape[0]))
             return st.with_mem(jnp.asarray(image))
 
@@ -241,7 +237,7 @@ class HartState:
         return dataclasses.replace(self, **kw)
 
     def with_mem(self, mem) -> "HartState":
-        with _x64():
+        with x64():
             return self.replace(mem=jnp.asarray(mem, U64))
 
     def or_image(self, image, base: int = 0) -> "HartState":
@@ -250,7 +246,7 @@ class HartState:
         Note: unlike ``machine.load_image`` (which overwrites), this merges
         — the semantics test harnesses want when layering fragments onto a
         fresh (zeroed) machine.  Use :meth:`with_mem` to replace memory."""
-        with _x64():
+        with x64():
             w = base >> 3
             img = jnp.asarray(image, U64)
             mem = self.mem.at[w:w + img.shape[0]].set(
@@ -456,7 +452,7 @@ class Fleet:
                     engine: Any = None) -> "Fleet":
         """Fleet of fresh harts, each booted from a raw uint64-word image
         (shorter images are zero-padded; an oversized one is an error)."""
-        with _x64():
+        with x64():
             imgs = [jnp.asarray(im, U64) for im in images]
             for i, im in enumerate(imgs):
                 if int(im.shape[0]) > mem_words:
@@ -494,7 +490,7 @@ class Fleet:
     def _stack(states: Sequence[HartState]) -> HartState:
         if not states:
             raise ValueError("Fleet needs at least one hart")
-        with _x64():
+        with x64():
             return jax.tree.map(lambda *xs: jnp.stack(xs), *states)
 
     # -- running ------------------------------------------------------------
@@ -590,7 +586,7 @@ class Fleet:
             raise MigrationError(
                 f"hart {src} guest {guest} was already migrated away")
         lay = programs.sched_layout(n)
-        with _x64():
+        with x64():
             mem = np.array(self._harts.mem)       # writable host copy
             done = np.asarray(self._harts.counters.done)
             virt = np.asarray(self._harts.virt)
@@ -688,7 +684,7 @@ class Fleet:
             raise MigrationError(f"hart {hart} guest {guest} is an "
                                  f"empty slot — nothing to park")
         lay = programs.sched_layout(n)
-        with _x64():
+        with x64():
             mem = np.array(self._harts.mem)       # writable host copy
             done = np.asarray(self._harts.counters.done)
             virt = np.asarray(self._harts.virt)
@@ -763,7 +759,7 @@ class Fleet:
                 f"cannot resolve workload {meta.get('workload')!r} from "
                 f"the guest checkpoint — pass workload= explicitly")
         lay = programs.sched_layout(n)
-        with _x64():
+        with x64():
             mem = np.array(self._harts.mem)       # writable host copy
             done = np.asarray(self._harts.counters.done)
             virt = np.asarray(self._harts.virt)
@@ -795,7 +791,7 @@ class Fleet:
         """
         if not (0 <= i < len(self._specs)):
             raise ValueError(f"hart {i} out of range")
-        with _x64():
+        with x64():
             want = tuple(self._harts.mem.shape[1:])
             got = tuple(jnp.shape(state.mem))
             if got != want:
@@ -832,7 +828,7 @@ class Fleet:
 
     @property
     def all_done(self) -> bool:
-        with _x64():
+        with x64():
             return bool(jnp.all(self._harts.counters.done))
 
     def __len__(self) -> int:
@@ -840,12 +836,12 @@ class Fleet:
 
     def __getitem__(self, i: int) -> HartState:
         """Per-hart view (scalar leaves) of slot `i`."""
-        with _x64():
+        with x64():
             return jax.tree.map(lambda x: x[i], self._harts)
 
     def counters(self) -> List[Counters]:
         """Per-hart :class:`Counters`, in fleet order."""
-        with _x64():
+        with x64():
             return [jax.tree.map(lambda x: x[i], self._harts.counters)
                     for i in range(len(self))]
 
@@ -863,7 +859,7 @@ class Fleet:
         from repro.core.hext import programs
         n = len(spec.guests)
         lay = programs.sched_layout(n)
-        with _x64():
+        with x64():
             res_w = lay.guest_res // 8
             cks = [int(self._harts.mem[i, res_w + k]) & MASK64
                    for k in range(n)]

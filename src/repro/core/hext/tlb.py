@@ -119,17 +119,17 @@ def compose_perms(vs_pte, g_pte, priv, sum_bit, mxr):
 
 
 def insert(tlb, va, pa, level, perm, virt, priv, sum_bit, mxr):
-    i = tlb["ptr"] % N_TLB
-    t = dict(tlb)
-    t["vpn"] = tlb["vpn"].at[i].set(jnp.asarray(va, U64) >> _u(12))
-    t["ppn"] = tlb["ppn"].at[i].set(jnp.asarray(pa, U64) >> _u(12))
-    t["level"] = tlb["level"].at[i].set(level)
-    t["perm"] = tlb["perm"].at[i].set(perm)
-    t["guest"] = tlb["guest"].at[i].set(virt)
-    t["priv"] = tlb["priv"].at[i].set(priv)
-    t["sum"] = tlb["sum"].at[i].set(sum_bit)
-    t["mxr"] = tlb["mxr"].at[i].set(mxr)
-    t["valid"] = tlb["valid"].at[i].set(True)
+    # a one-hot select, not a scatter: with nine single-entry scatters here
+    # a 1,152-hart fleet on a TPU v5e miscounted `walks` (guest harts only)
+    # and with the select it matches the goldens; scatter_probe.py checks
+    # the scatter and the select alone on a chip
+    slot = jnp.arange(N_TLB) == tlb["ptr"] % N_TLB
+    new = {"vpn": jnp.asarray(va, U64) >> _u(12),
+           "ppn": jnp.asarray(pa, U64) >> _u(12),
+           "level": level, "perm": perm, "guest": virt, "priv": priv,
+           "sum": sum_bit, "mxr": mxr, "valid": True}
+    t = {k: jnp.where(slot, jnp.asarray(v, tlb[k].dtype), tlb[k])
+         for k, v in new.items()}
     t["ptr"] = tlb["ptr"] + 1
     return t
 

@@ -49,6 +49,7 @@ from repro.core.hext import csr as C
 from repro.core.hext import oracle
 from repro.core.hext import programs
 from repro.core.hext.engine import DIFF_COUNTERS as _COUNTERS
+from repro.core.hext.engine import use_compile_cache
 from repro.core.hext.programs import (Asm, Image, G_L0, G_L1, G_L2,
                                       S_L0, S_L1, S_L2, SATP_SV39,
                                       PTE_V, PTE_R, PTE_W, PTE_X, PTE_U,
@@ -1186,4 +1187,5 @@ def main(argv: Optional[List[str]] = None) -> int:
 
 
 if __name__ == "__main__":
+    use_compile_cache()
     sys.exit(main())

@@ -1,5 +1,6 @@
 """Dispatching wrapper: TPU → Pallas kernel, CPU → jnp ref (identical
-semantics; the dry-run lowers this path)."""
+semantics, out-of-range coordinates included; the dry-run lowers this
+path)."""
 from __future__ import annotations
 
 import jax

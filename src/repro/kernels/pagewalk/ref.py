@@ -16,6 +16,10 @@ def two_stage_translate_ref(vs_table, vs_perm, g_table, tenant, req, page,
     """vs_table [T,R,P] int32; g_table [T,G] int32; coords [B] int32;
     want_write [B] bool → (slot [B] int32, fault [B] bool, stage [B] int32).
     """
+    # jnp arrays, so that out-of-range coordinates follow jnp indexing
+    # (from the end when negative, then clamped) for numpy inputs too
+    vs_table, vs_perm, g_table = map(jnp.asarray,
+                                     (vs_table, vs_perm, g_table))
     tp = vs_table[tenant, req, page]
     perm = vs_perm[tenant, req, page]
     want = jnp.where(want_write, PERM_W, PERM_R)

@@ -6,12 +6,12 @@ hypothesis (absent from the CI container, which used to skip this file
 silently).  Case counts are kept small for the push gate; the values are
 deterministic, so a failure's ``case`` index is directly reproducible.
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.hext import csr as C
+from repro.core.hext.bits import x64
 
 N_CASES = 16
 
@@ -28,12 +28,12 @@ def _vals(test_tag: str, n: int = N_CASES):
 
 
 def _csrs():
-    with jax.experimental.enable_x64():
+    with x64():
         return C.init_csrs()
 
 
 def _rw(csrs, addr, value, priv=3, virt=False):
-    with jax.experimental.enable_x64():
+    with x64():
         new, ok, vinst = C.csr_write(
             csrs, jnp.asarray(addr, jnp.int32),
             jnp.asarray(value, jnp.uint64),
@@ -42,7 +42,7 @@ def _rw(csrs, addr, value, priv=3, virt=False):
 
 
 def _rd(csrs, addr, priv=3, virt=False):
-    with jax.experimental.enable_x64():
+    with x64():
         val, ok, vinst = C.csr_read(
             csrs, jnp.asarray(addr, jnp.int32),
             jnp.asarray(priv, jnp.int32), jnp.asarray(virt, bool))
@@ -141,7 +141,7 @@ def test_csr_file_matches_oracle(v):
             onew, ook, ovi = oracle.csr_write(
                 oracle.init_csrs(), addr, v, priv, virt)
             assert (jok, jvi) == (ook, ovi), (hex(addr), priv, virt)
-            with jax.experimental.enable_x64():   # u64 host reads need x64
+            with x64():   # u64 host reads need x64
                 jlist = [int(x) for x in np.asarray(jnew)]
             assert jlist == onew, (hex(addr), priv, virt)
             jv, jok, jvi = _rd(jnew, addr, priv, virt)

@@ -31,6 +31,7 @@ import numpy as np
 import pytest
 
 from repro.core.hext import checkpoint, engine, programs
+from repro.core.hext.bits import x64
 from repro.core.hext.sim import (Fleet, MigrationError, StaleHartsError,
                                  MASK64, checksum_ok)
 
@@ -47,7 +48,7 @@ def _assert_states_identical(a, b):
     la = jax.tree_util.tree_leaves(a)
     lb = jax.tree_util.tree_leaves(b)
     assert len(la) == len(lb)
-    with jax.experimental.enable_x64():
+    with x64():
         for x, y in zip(la, lb):
             assert np.array_equal(np.asarray(x), np.asarray(y))
 
@@ -292,6 +293,8 @@ def test_sharded_engine_multi_device_matches_jit():
         print('SHARDED-MULTI-OK')
     """)
     env = dict(os.environ)
+    # the child must never reach for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     env["PYTHONPATH"] = str(REPO / "src")
     res = subprocess.run([sys.executable, "-c", script], env=env,

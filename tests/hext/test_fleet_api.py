@@ -10,6 +10,7 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core.hext import machine, programs
+from repro.core.hext.bits import x64
 from repro.core.hext.sim import Counters, Fleet, HartState, checksum_ok
 
 MAX_TICKS = 30000
@@ -19,7 +20,7 @@ CHUNK = 2048
 def _legacy_host_loop(raw_batch, max_ticks, chunk):
     """The pre-Fleet algorithm: jitted vmapped chunk scan with a per-chunk
     `bool(jnp.all(...))` host sync — the reference for counter parity."""
-    with jax.experimental.enable_x64():
+    with x64():
         def body(s, _):
             return machine.step(s), None
         one = lambda s: jax.lax.scan(body, s, None, length=chunk)[0]
@@ -42,7 +43,7 @@ def fleet_and_legacy():
     fleet = Fleet.boot([w for w, _ in pairs], guest=guests)
     fleet.run(MAX_TICKS, chunk=CHUNK)
 
-    with jax.experimental.enable_x64():
+    with x64():
         states = [HartState.boot(w, guest=g).to_raw() for w, g in pairs]
         raw = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
     raw = _legacy_host_loop(raw, MAX_TICKS, CHUNK)
@@ -96,7 +97,7 @@ def test_counters_ok_is_mod_2_64():
     top = (1 << 63) | 5
     assert checksum_ok(top, top)
     assert not checksum_ok(top & ((1 << 63) - 1), top)
-    with jax.experimental.enable_x64():
+    with x64():
         z = Counters.zero()
         assert z.ok(0) and not z.ok(top)
 

@@ -14,6 +14,7 @@ import pytest
 import numpy as np
 
 from repro.core.hext import isa
+from repro.core.hext.bits import x64
 
 I64_MIN = -(1 << 63)
 U64_MAX = (1 << 64) - 1
@@ -36,7 +37,7 @@ def _pairs(tag: str, signed: bool, n: int = N_CASES):
 
 
 def _u(x):
-    with jax.experimental.enable_x64():
+    with x64():
         return jnp.asarray(x % (1 << 64), jnp.uint64)
 
 
@@ -51,7 +52,7 @@ def _as_u64(i):
 
 @pytest.mark.parametrize("a,b", _pairs("divs", signed=True))
 def test_divs_matches_riscv_semantics(a, b):
-    with jax.experimental.enable_x64():
+    with x64():
         got = _as_i64(isa.divs(_u(a), _u(b)))
     if b == 0:
         want = -1
@@ -66,7 +67,7 @@ def test_divs_matches_riscv_semantics(a, b):
 
 @pytest.mark.parametrize("a,b", _pairs("rems", signed=True))
 def test_rems_matches_riscv_semantics(a, b):
-    with jax.experimental.enable_x64():
+    with x64():
         got = _as_i64(isa.rems(_u(a), _u(b)))
     if b == 0:
         want = a
@@ -81,14 +82,14 @@ def test_rems_matches_riscv_semantics(a, b):
 
 @pytest.mark.parametrize("a,b", _pairs("mulhu", signed=False))
 def test_mulhu_matches_python(a, b):
-    with jax.experimental.enable_x64():
+    with x64():
         got = int(isa.mulhu(_u(a), _u(b)))
     assert got == (a * b) >> 64
 
 
 @pytest.mark.parametrize("a,b", _pairs("mulh", signed=True))
 def test_mulh_matches_python(a, b):
-    with jax.experimental.enable_x64():
+    with x64():
         got = _as_i64(isa.mulh(_u(_as_u64(a)), _u(_as_u64(b))))
     assert got == (a * b) >> 64
 
@@ -96,7 +97,7 @@ def test_mulh_matches_python(a, b):
 @pytest.mark.parametrize("a,b", _pairs("mulhsu", signed=True))
 def test_mulhsu_matches_python(a, b):
     b = _as_u64(b)                       # rs2 is unsigned for mulhsu
-    with jax.experimental.enable_x64():
+    with x64():
         got = _as_i64(isa.mulhsu(_u(_as_u64(a)), _u(b)))
     assert got == (a * b) >> 64
 
@@ -104,7 +105,7 @@ def test_mulhsu_matches_python(a, b):
 @pytest.mark.parametrize("bits", [8, 12, 16, 32])
 def test_sext_matches_python(bits):
     for v, _ in _pairs(f"sext{bits}", signed=False, n=8):
-        with jax.experimental.enable_x64():
+        with x64():
             got = _as_i64(isa.sext(_u(v), bits))
         low = v & ((1 << bits) - 1)
         want = low - (1 << bits) if low >= (1 << (bits - 1)) else low
@@ -116,7 +117,7 @@ def test_mem_write_read_roundtrip(size):
     nbytes = 1 << size
     for val, off in _pairs(f"mem{size}", signed=False, n=6):
         off = (off % 8 // nbytes) * nbytes        # naturally aligned
-        with jax.experimental.enable_x64():
+        with x64():
             mem = jnp.zeros((4,), jnp.uint64)
             mem = isa.mem_write(mem, _u(8 + off), _u(val), size)
             rd = int(isa.mem_read(mem, _u(8 + off), size,
@@ -130,7 +131,7 @@ def test_alu_helpers_match_oracle(a, b):
     the two independent div/rem/mulh implementations must agree."""
     from repro.core.hext import oracle
     au, bu = _as_u64(a), _as_u64(b)
-    with jax.experimental.enable_x64():
+    with x64():
         assert int(isa.divs(_u(au), _u(bu))) == oracle._divs(au, bu)
         assert int(isa.rems(_u(au), _u(bu))) == oracle._rems(au, bu)
         assert int(isa.mulhu(_u(au), _u(bu))) == oracle._mulhu(au, bu)
@@ -211,7 +212,7 @@ def test_traced_decode_matches_decode_word():
     over the same tables for every sweep word (one vmapped trace)."""
     from repro.core.hext import decode as D
     words = _decode_words()
-    with jax.experimental.enable_x64():
+    with x64():
         uops = jax.jit(jax.vmap(D.decode))(jnp.asarray(words, jnp.uint64))
         uops = jax.tree.map(np.asarray, uops)
     for i, w in enumerate(words):

@@ -4,13 +4,13 @@ the machine's software TLB and the oracle's mirror of it.  rs1 = x0
 stays the conservative full-class flush; superpage entries match (and
 are dropped) by their level mask.
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core.hext import oracle
 from repro.core.hext import tlb as TLB
+from repro.core.hext.bits import x64
 
 
 def _count_valid(t):
@@ -28,7 +28,7 @@ def _mk_machine_tlb():
 
 
 def test_machine_flush_va_scoped_native():
-    with jax.experimental.enable_x64():
+    with x64():
         t = _mk_machine_tlb()
         out = TLB.flush(t, native_only=True, va=0x3000)
         # only the native 0x3000 entry drops: guest 0x3000 and native
@@ -39,7 +39,7 @@ def test_machine_flush_va_scoped_native():
 
 
 def test_machine_flush_va_matches_superpage_by_level():
-    with jax.experimental.enable_x64():
+    with x64():
         t = _mk_machine_tlb()
         # any VA inside the 2M superpage selects it via the level mask
         out = TLB.flush(t, native_only=True, va=0x200000 + 0x5A000)
@@ -48,7 +48,7 @@ def test_machine_flush_va_matches_superpage_by_level():
 
 
 def test_machine_flush_full_class_without_va():
-    with jax.experimental.enable_x64():
+    with x64():
         t = _mk_machine_tlb()
         out = TLB.flush(t, native_only=True)
         v = np.asarray(out["valid"])[:4]
@@ -59,7 +59,7 @@ def test_machine_flush_full_class_without_va():
 
 
 def test_machine_flush_where_addr_conditions():
-    with jax.experimental.enable_x64():
+    with x64():
         t = _mk_machine_tlb()
         zb = jnp.asarray(False)
         tb = jnp.asarray(True)

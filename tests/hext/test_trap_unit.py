@@ -19,7 +19,6 @@ Plus the ISSUE 3 conformance satellites:
 
 These paths were previously exercised only indirectly through workloads.
 """
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -30,6 +29,7 @@ from repro.core.hext import programs
 from repro.core.hext import tlb as TLB
 from repro.core.hext import translate as X
 from repro.core.hext import trap as TR
+from repro.core.hext.bits import x64
 from tests.hext.conftest import (build_vs_identity, exit_with,
                                  m_handler_capture, prologue, result, run_asm)
 
@@ -61,7 +61,7 @@ def _route(csrs, priv, virt, cause, is_int):
 
 class TestPendingPriority:
     def test_mei_beats_msi_beats_mti(self):
-        with jax.experimental.enable_x64():
+        with x64():
             allm = C.IP_MEIP | C.IP_MSIP | C.IP_MTIP
             c = _csrs(mip=allm, mie=allm, mstatus=C.MSTATUS_MIE)
             assert _pending(c) == (True, 11)
@@ -72,7 +72,7 @@ class TestPendingPriority:
             assert _pending(c) == (True, 7)
 
     def test_m_interrupts_beat_s_interrupts(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(mip=C.IP_MTIP | C.IP_SEIP,
                       mie=C.IP_MTIP | C.IP_SEIP,
                       mideleg=C.MIDELEG_FORCED | C.IP_SEIP,
@@ -81,7 +81,7 @@ class TestPendingPriority:
             assert _pending(c, priv=1) == (True, 7)
 
     def test_s_priority_sei_ssi_sti(self):
-        with jax.experimental.enable_x64():
+        with x64():
             alls = C.IP_SEIP | C.IP_SSIP | C.IP_STIP
             c = _csrs(mip=alls, mie=alls,
                       mideleg=C.MIDELEG_FORCED | alls,
@@ -99,7 +99,7 @@ class TestPendingPriority:
 
 class TestPendingEnables:
     def test_m_gated_by_mie_at_m_only(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(mip=C.IP_MSIP, mie=C.IP_MSIP)   # mstatus.MIE = 0
             assert _pending(c, priv=3) == (False, 0)
             # from lower privilege, M interrupts always fire
@@ -107,7 +107,7 @@ class TestPendingEnables:
             assert _pending(c, priv=0)[0]
 
     def test_hs_gated_by_sie_at_hs_only(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(mip=C.IP_SSIP, mie=C.IP_SSIP,
                       mideleg=C.MIDELEG_FORCED | C.IP_SSIP)
             assert _pending(c, priv=1) == (False, 0)  # SIE=0 at HS
@@ -120,13 +120,13 @@ class TestPendingEnables:
     def test_hs_interrupt_preempts_vs_regardless_of_guest_sie(self):
         """The scheduler relies on this: STI delegated to HS fires while a
         guest runs in VS even with all guest enables clear."""
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(mip=C.IP_STIP, mie=C.IP_STIP,
                       mideleg=C.MIDELEG_FORCED | C.IP_STIP)
             assert _pending(c, priv=1, virt=True) == (True, 5)
 
     def test_vs_interrupt_gated_by_vsstatus_sie(self):
-        with jax.experimental.enable_x64():
+        with x64():
             base = dict(mip=C.IP_VSSIP, mie=C.IP_VSSIP,
                         hideleg=C.IP_VSSIP)
             c = _csrs(**base)
@@ -140,7 +140,7 @@ class TestPendingEnables:
     def test_vs_interrupt_not_deliverable_without_virt(self):
         """hideleg'd VS interrupt targets VS — with V=0 it must not fire as
         a VS-level interrupt."""
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(mip=C.IP_VSSIP, mie=C.IP_VSSIP, hideleg=C.IP_VSSIP,
                       vsstatus=C.MSTATUS_SIE)
             assert _pending(c, priv=1, virt=False) == (False, 0)
@@ -152,12 +152,12 @@ class TestPendingEnables:
 
 class TestRouteMatrix:
     def test_exception_default_to_m(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs()
             assert _route(c, 1, False, C.EXC_LPAGE_FAULT, False) == (3, False)
 
     def test_exception_medeleg_to_hs(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(medeleg=1 << C.EXC_LPAGE_FAULT)
             assert _route(c, 1, False, C.EXC_LPAGE_FAULT, False) == (1, False)
             # HS faults never route to VS even with hedeleg set
@@ -166,7 +166,7 @@ class TestRouteMatrix:
             assert _route(c, 1, False, C.EXC_LPAGE_FAULT, False) == (1, False)
 
     def test_exception_hedeleg_to_vs_only_when_virt(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(medeleg=1 << C.EXC_LPAGE_FAULT,
                       hedeleg=1 << C.EXC_LPAGE_FAULT)
             assert _route(c, 1, True, C.EXC_LPAGE_FAULT, False) == (1, True)
@@ -175,13 +175,13 @@ class TestRouteMatrix:
             assert _route(c, 1, True, C.EXC_LPAGE_FAULT, False) == (1, False)
 
     def test_traps_from_m_never_delegate(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(medeleg=0xFFFF, mideleg=0xFFFF, hedeleg=0xFFFF)
             assert _route(c, 3, False, C.EXC_LPAGE_FAULT, False) == (3, False)
             assert _route(c, 3, False, 3, True) == (3, False)
 
     def test_interrupt_mideleg_hideleg_chain(self):
-        with jax.experimental.enable_x64():
+        with x64():
             # VSSI: mideleg VS bits are forced-one; hideleg decides HS vs VS
             c = _csrs(hideleg=C.IP_VSSIP)
             assert _route(c, 1, True, 2, True) == (1, True)    # → VS
@@ -200,7 +200,7 @@ class TestRouteMatrix:
 
 class TestAdvanceTimers:
     def test_disarmed_never_touches_mip(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(mip=C.IP_SSIP)              # software-injected bit
             for _ in range(3):
                 c = machine._advance_timers(c)
@@ -208,7 +208,7 @@ class TestAdvanceTimers:
             assert int(c[C.R_MIP]) == C.IP_SSIP   # untouched
 
     def test_armed_mtimecmp_sets_then_clears_mtip(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(mtimecmp=2)
             c = machine._advance_timers(c)        # mtime=1 < 2
             assert int(c[C.R_MIP]) & C.IP_MTIP == 0
@@ -220,7 +220,7 @@ class TestAdvanceTimers:
             assert int(c[C.R_MIP]) & C.IP_MTIP == 0
 
     def test_stimecmp_and_vstimecmp_drive_their_bits(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(stimecmp=1, vstimecmp=2)
             c = machine._advance_timers(c)
             assert int(c[C.R_MIP]) & C.IP_STIP
@@ -239,7 +239,7 @@ class TestTlbPrivTags:
                 jnp.asarray(mxr, bool))
 
     def test_cross_priv_lookup_misses(self):
-        with jax.experimental.enable_x64():
+        with x64():
             t = TLB.init_tlb()
             virt = jnp.asarray(False, bool)
             p1 = self._mk(1)
@@ -255,7 +255,7 @@ class TestTlbPrivTags:
             assert not bool(hit)
 
     def test_sum_and_mxr_context_mismatch_misses(self):
-        with jax.experimental.enable_x64():
+        with x64():
             t = TLB.init_tlb()
             virt = jnp.asarray(False, bool)
             ctx = self._mk(1, sum_bit=True)
@@ -293,7 +293,7 @@ def _pte(pa, perms):
 class TestReservedPte:
     def test_w_only_pte_faults_first_stage(self):
         """W=1,R=0 is reserved — previously walked through as a pointer."""
-        with jax.experimental.enable_x64():
+        with x64():
             P = X.PTE_V | X.PTE_W | X.PTE_A | X.PTE_D
             mem = _mem_with({
                 0x1000: _pte(0x2000, X.PTE_V),            # L2 → L1
@@ -310,7 +310,7 @@ class TestReservedPte:
     def test_w_only_nonleaf_position_faults(self):
         """A reserved encoding in a *non-leaf* slot must fault too, not be
         dereferenced as a next-level pointer."""
-        with jax.experimental.enable_x64():
+        with x64():
             mem = _mem_with({
                 0x1000: _pte(0x2000, X.PTE_V | X.PTE_W),  # reserved pointer
                 0x2000: _pte(0x3000, X.PTE_V),
@@ -324,7 +324,7 @@ class TestReservedPte:
             assert int(xr.cause) == C.EXC_IPAGE_FAULT
 
     def test_w_only_pte_faults_g_stage(self):
-        with jax.experimental.enable_x64():
+        with x64():
             P = X.PTE_V | X.PTE_W | X.PTE_U | X.PTE_A | X.PTE_D
             mem = _mem_with({
                 0x1000: _pte(0x2000, X.PTE_V),
@@ -353,7 +353,7 @@ class TestHlvxGStage:
     def test_hlvx_reads_x_only_g_stage_page(self):
         """HLVX requires execute permission INSTEAD of read — at both
         stages.  An X-only G-stage page must satisfy it."""
-        with jax.experimental.enable_x64():
+        with x64():
             xonly = X.PTE_V | X.PTE_X | X.PTE_U | X.PTE_A | X.PTE_D
             mem, csrs = self._setup(xonly)
             xr = X.translate(mem, csrs, jnp.asarray(3, jnp.int32),
@@ -369,7 +369,7 @@ class TestHlvxGStage:
             assert int(xr.cause) == C.EXC_LGUEST_PAGE_FAULT
 
     def test_hlvx_faults_on_r_only_g_stage_page(self):
-        with jax.experimental.enable_x64():
+        with x64():
             ronly = X.PTE_V | X.PTE_R | X.PTE_U | X.PTE_A | X.PTE_D
             mem, csrs = self._setup(ronly)
             xr = X.translate(mem, csrs, jnp.asarray(3, jnp.int32),
@@ -382,7 +382,7 @@ class TestHlvxGStage:
     def test_hlvx_implicit_walk_fault_reports_load_cause(self):
         """An hlvx whose VS-stage PTE *fetch* guest-faults must report the
         original (load) access type, not the execute override."""
-        with jax.experimental.enable_x64():
+        with x64():
             mem = np.zeros((1 << 13,), dtype=np.uint64)   # 64 KiB
 
             def poke(addr, val):
@@ -418,7 +418,7 @@ class TestOobPaAccessFault:
     access type instead — during walks and on the final access."""
 
     def test_walk_pte_beyond_memory_faults_per_access_type(self):
-        with jax.experimental.enable_x64():
+        with x64():
             mem = jnp.zeros((1 << 12,), jnp.uint64)       # 32 KiB
             # satp root far beyond memory: the level-2 PTE fetch is OOB
             csrs = _csrs(satp=SV39 | ((1 << 20) >> 12))
@@ -434,7 +434,7 @@ class TestOobPaAccessFault:
     def test_walk_inner_pte_beyond_memory_faults(self):
         """An in-range root whose next-level pointer leaves memory must
         fault at that level, not wrap and keep walking."""
-        with jax.experimental.enable_x64():
+        with x64():
             mem = _mem_with({0x1000: _pte(1 << 21, X.PTE_V)})  # L2 → OOB L1
             csrs = _csrs(satp=SV39 | (0x1000 >> 12))
             xr = X.translate(jnp.asarray(mem), csrs,
@@ -447,7 +447,7 @@ class TestOobPaAccessFault:
     def test_gstage_walk_pte_beyond_memory_faults(self):
         """G-stage PTE fetches are bounds-checked too — and report the
         access-fault cause, not a guest-page-fault."""
-        with jax.experimental.enable_x64():
+        with x64():
             mem = jnp.zeros((1 << 12,), jnp.uint64)
             hgatp = jnp.uint64(SV39 | ((1 << 20) >> 12))
             xr = X.g_translate(mem, hgatp, jnp.uint64(0x5000),
@@ -567,14 +567,14 @@ class TestHtimedelta:
             C.R_SCOUNTEREN].set(jnp.uint64(7))
 
     def _time(self, c, priv, virt):
-        with jax.experimental.enable_x64():
+        with x64():
             v, ok, vinst = C.csr_read(c, jnp.asarray(0xC01, jnp.int32),
                                       jnp.asarray(priv, jnp.int32),
                                       jnp.asarray(virt, bool))
             return int(v), bool(ok), bool(vinst)
 
     def test_time_shifted_under_v1_only(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = self._open_counters(_csrs(mtime=1000))
             c = c.at[C.R_HTIMEDELTA].set(jnp.uint64(self.M64 - 99))  # -100
             assert self._time(c, 1, False)[0] == 1000    # HS: raw mtime
@@ -582,7 +582,7 @@ class TestHtimedelta:
             assert self._time(c, 0, True)[0] == 900      # VU too
 
     def test_write_preserved_from_hs_vinst_from_vs(self):
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs()
             new, ok, vinst = C.csr_write(
                 c, jnp.asarray(0x605, jnp.int32), jnp.uint64(0x1234),
@@ -603,7 +603,7 @@ class TestHtimedelta:
     def test_vstimecmp_compares_guest_time(self):
         """VSTIP must arm on mtime + htimedelta: with delta = -30 and
         vstimecmp = 50, the comparator fires at mtime 80, not 50."""
-        with jax.experimental.enable_x64():
+        with x64():
             c = _csrs(vstimecmp=50, mtime=49)
             c = c.at[C.R_HTIMEDELTA].set(jnp.uint64(self.M64 - 29))  # -30
             c = machine._advance_timers(c)               # mtime 50: vs 20
@@ -619,14 +619,14 @@ class TestHtimedelta:
 
 class TestTimeCounterEnable:
     def _rd(self, c, priv, virt):
-        with jax.experimental.enable_x64():
+        with x64():
             _, ok, vinst = C.csr_read(c, jnp.asarray(0xC01, jnp.int32),
                                       jnp.asarray(priv, jnp.int32),
                                       jnp.asarray(virt, bool))
             return bool(ok), bool(vinst)
 
     def _c(self, m=0, h=0, s=0):
-        with jax.experimental.enable_x64():
+        with x64():
             c = C.init_csrs()
             return c.at[C.R_MCOUNTEREN].set(jnp.uint64(m)).at[
                 C.R_HCOUNTEREN].set(jnp.uint64(h)).at[

@@ -43,6 +43,23 @@ def test_pagewalk_kernel_matches_ref_shapes(B):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
+def test_pagewalk_kernel_matches_ref_out_of_range():
+    """Negative and too-large coordinates, and stage-1 entries past the
+    stage-2 table, follow jnp indexing on both paths."""
+    rng = np.random.RandomState(7)
+    vs, perm, g = _random_tables(rng)
+    vs = rng.randint(-1, 32 + 8, size=vs.shape).astype(np.int32)
+    B = 256
+    t = rng.randint(-5, 3 + 3, B).astype(np.int32)
+    r = rng.randint(-6, 4 + 3, B).astype(np.int32)
+    p = rng.randint(-20, 16 + 5, B).astype(np.int32)
+    w = rng.randint(0, 2, B).astype(bool)
+    a = two_stage_translate(vs, perm, g, t, r, p, w, force="ref")
+    b = two_stage_translate(vs, perm, g, t, r, p, w, force="interpret")
+    for x, y in zip(a, b):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
 @settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 1000))
 def test_pagewalk_property_fault_iff_any_stage_invalid(seed):
