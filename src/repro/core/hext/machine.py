@@ -190,7 +190,8 @@ def fetch(state: Dict, csrs1, m_run):
             lambda m, c, p, v, va: X.translate(m, c, p, v, va, X.ACC_X))(
             state["mem"], csrs1, priv0, virt0, pc0)
 
-    xrw = jax.lax.cond(jnp.any(need), walk, lambda: _zero_xr(batch))
+    with jax.named_scope("walk"):
+        xrw = jax.lax.cond(jnp.any(need), walk, lambda: _zero_xr(batch))
     pa = jnp.where(use_f, tv.pa, xrw.pa)
     fault_w = ~use_f & xrw.fault
     xr = xrw._replace(pa=pa, fault=fault_w)
@@ -233,9 +234,10 @@ def execute(state: Dict, csrs1, tlb1, instr, m_exec):
     pc0, priv0, virt0 = state["pc"], state["priv"], state["virt"]
 
     # ---- decode ------------------------------------------------------------
-    uop = jax.vmap(D.decode)(instr)
-    rv1 = _gather(state["regs"], uop.rs1)
-    rv2 = _gather(state["regs"], uop.rs2)
+    with jax.named_scope("decode"):
+        uop = jax.vmap(D.decode)(instr)
+        rv1 = _gather(state["regs"], uop.rs1)
+        rv2 = _gather(state["regs"], uop.rs2)
 
     # ---- data translation (TLB fast path + cond-gated walk) ----------------
     q = jax.vmap(isa.mem_query)(csrs1, priv0, virt0, uop, rv1)
@@ -254,18 +256,21 @@ def execute(state: Dict, csrs1, tlb1, instr, m_exec):
             state["mem"], csrs1, priv0, virt0, q.addr, q.macc,
             q.force_virt, q.hlvx)
 
-    xrw = jax.lax.cond(jnp.any(need_d), walk,
-                       lambda: _zero_xr(pc0.shape[0]))
+    with jax.named_scope("walk"):
+        xrw = jax.lax.cond(jnp.any(need_d), walk,
+                           lambda: _zero_xr(pc0.shape[0]))
     pa = jnp.where(use_d, tv.pa, xrw.pa)
     fault_w = ~use_d & xrw.fault
     xr = xrw._replace(pa=pa, fault=fault_w)
 
     # ---- SYSTEM contributor (cond-gated: CSR where-chains are heavy) -------
     sys_need = m_exec & (uop.cls == D.CLS_SYSTEM) & (uop.f3 != _u(4))
-    sys = jax.lax.cond(
-        jnp.any(sys_need),
-        lambda: jax.vmap(isa.exec_sys)(csrs1, priv0, virt0, pc0, rv1, uop),
-        lambda: _neutral_sys(csrs1))
+    with jax.named_scope("system"):
+        sys = jax.lax.cond(
+            jnp.any(sys_need),
+            lambda: jax.vmap(isa.exec_sys)(csrs1, priv0, virt0, pc0, rv1,
+                                           uop),
+            lambda: _neutral_sys(csrs1))
 
     # ---- merge contributors -------------------------------------------------
     st = dict(state)
@@ -299,11 +304,13 @@ def retire(state: Dict, csrs1, tlb1, eo: isa.ExecOut, f_fetch, fetch_fault,
         return jax.vmap(TR.take_trap)(csrs1, priv0, virt0, pc0, t_cause,
                                       take, t_tval, t_tval2, t_gva, t_tinst)
 
-    trap_csrs, trap_pc, trap_priv, trap_virt, handled = jax.lax.cond(
-        jnp.any(m_trap), trap,
-        lambda: (csrs1, jnp.zeros((batch,), U64),
-                 jnp.zeros((batch,), jnp.int32), jnp.zeros((batch,), bool),
-                 jnp.zeros((batch,), jnp.int32)))
+    with jax.named_scope("trap"):
+        trap_csrs, trap_pc, trap_priv, trap_virt, handled = jax.lax.cond(
+            jnp.any(m_trap), trap,
+            lambda: (csrs1, jnp.zeros((batch,), U64),
+                     jnp.zeros((batch,), jnp.int32),
+                     jnp.zeros((batch,), bool),
+                     jnp.zeros((batch,), jnp.int32)))
 
     out = dict(state)
     out["pc"] = jnp.where(m_trap, trap_pc,
@@ -361,29 +368,41 @@ def retire(state: Dict, csrs1, tlb1, eo: isa.ExecOut, f_fetch, fetch_fault,
 
 def step_batched(state: Dict) -> Dict:
     """One architectural tick for a (B, ...) hart batch — the fused
-    fetch → decode → execute → retire pipeline."""
+    fetch → decode → execute → retire pipeline.
+
+    Each stage runs under a ``jax.named_scope`` (``timers``, ``fetch``,
+    ``decode``, ``execute``, ``retire``; ``walk``, ``system`` and ``trap``
+    around the conds nested in them), which names the stage in every HLO
+    op's metadata and so in a device trace; the compiled program is the
+    same without them."""
     frozen = state["done"]
 
-    # ---- 0. virtual CLINT tick (frozen harts keep their old csrs) ----------
-    csrs1 = jax.vmap(_advance_timers)(state["csrs"])
+    with jax.named_scope("timers"):
+        # ---- 0. virtual CLINT tick (frozen harts keep their old csrs) ------
+        csrs1 = jax.vmap(_advance_timers)(state["csrs"])
 
-    # ---- 1. CheckInterrupts (paper Fig 2) ----------------------------------
-    take, icause = jax.vmap(TR.pending_interrupt)(csrs1, state["priv"],
-                                                  state["virt"])
-    # halted harts wake on any pending+locally-enabled interrupt — the spec
-    # says WFI resumes on (mip & mie) != 0 regardless of mstatus.MIE/SIE
-    # global gating; `take` additionally routes through the trap path when
-    # the interrupt is actually deliverable at the current privilege.
-    wake = (csrs1[:, C.R_MIP] & csrs1[:, C.R_MIE]) != _u(0)
-    idle = state["halted"] & ~take & ~wake
-    m_run = ~frozen & ~take & ~idle
-    m_int = ~frozen & take
+        # ---- 1. CheckInterrupts (paper Fig 2) ------------------------------
+        take, icause = jax.vmap(TR.pending_interrupt)(csrs1, state["priv"],
+                                                      state["virt"])
+        # halted harts wake on any pending+locally-enabled interrupt — the
+        # spec says WFI resumes on (mip & mie) != 0 regardless of
+        # mstatus.MIE/SIE global gating; `take` additionally routes through
+        # the trap path when the interrupt is actually deliverable at the
+        # current privilege.
+        wake = (csrs1[:, C.R_MIP] & csrs1[:, C.R_MIE]) != _u(0)
+        idle = state["halted"] & ~take & ~wake
+        m_run = ~frozen & ~take & ~idle
+        m_int = ~frozen & take
 
     # ---- 2..4. fetch → decode+execute → retire -----------------------------
-    instr, fetch_fault, f_fetch, tlb1, walked_f = fetch(state, csrs1, m_run)
-    eo, _ = execute(state, csrs1, tlb1, instr, m_run & ~fetch_fault)
-    return retire(state, csrs1, tlb1, eo, f_fetch, fetch_fault, walked_f,
-                  (frozen, take, icause, m_run, m_int))
+    with jax.named_scope("fetch"):
+        instr, fetch_fault, f_fetch, tlb1, walked_f = fetch(state, csrs1,
+                                                            m_run)
+    with jax.named_scope("execute"):        # decode is nested inside
+        eo, _ = execute(state, csrs1, tlb1, instr, m_run & ~fetch_fault)
+    with jax.named_scope("retire"):
+        return retire(state, csrs1, tlb1, eo, f_fetch, fetch_fault,
+                      walked_f, (frozen, take, icause, m_run, m_int))
 
 
 def step(state: Dict) -> Dict:

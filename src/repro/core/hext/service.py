@@ -38,18 +38,28 @@ The progress monitor doubles as the straggler accounting that used to
 live in the retired ``repro.runtime.fault_tolerance`` scaffolding (its
 retry-with-restore supervisor loop became the recover phase here);
 ``stragglers()`` reports lanes currently behind.
+
+Each phase of a round runs inside a ``service.<phase>`` span
+(``telemetry``, recorded only where a sink is installed);
+``stats["readback_bytes"]`` counts the device bytes the control plane
+reads back, and each ``Job`` carries host-clock submit, start and done
+times (DESIGN.md §8e).
 """
 from __future__ import annotations
 
 import dataclasses
 import os
 import tempfile
+import time
+import weakref
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.core.hext import checkpoint as _checkpoint
 from repro.core.hext import programs as _programs
+from repro.core.hext import telemetry
 from repro.core.hext.policies import (BinPackPolicy, JobView, LaneView,
                                       PlacementPolicy, size_bucket,
                                       workload_footprint)
@@ -87,6 +97,11 @@ class Job:
     ok: Optional[bool] = None
     parked_path: Optional[str] = None
     events: List[str] = dataclasses.field(default_factory=list)
+    # host clock (time.perf_counter) at submission, first placement and
+    # harvest: queue wait is t_start - t_submit, run time t_done - t_start
+    t_submit: Optional[float] = None
+    t_start: Optional[float] = None
+    t_done: Optional[float] = None
 
     @property
     def terminal(self) -> bool:
@@ -208,7 +223,11 @@ class FleetService:
                            if w.name == "idle"), None)
         self.stats = {"submitted": 0, "rejected": 0, "completed": 0,
                       "failed": 0, "migrations": 0, "parks": 0,
-                      "resumes": 0, "recoveries": 0, "balloons": 0}
+                      "resumes": 0, "recoveries": 0, "balloons": 0,
+                      "readback_bytes": 0}
+        # device arrays already counted in readback_bytes, by id: JAX
+        # serves a second np.asarray of one array from its host copy
+        self._read = weakref.WeakValueDictionary()
 
     # -- construction helpers -----------------------------------------------
     @staticmethod
@@ -270,7 +289,7 @@ class FleetService:
                   name=getattr(workload, "name", f"job{jid}"),
                   tenant=int(tenant), mode=mode,
                   golden=int(workload.golden()) & MASK64,
-                  submit_slice=self._slices)
+                  submit_slice=self._slices, t_submit=time.perf_counter())
         self._jobs[jid] = job
         self.stats["submitted"] += 1
         if not self.policy.admit(len(self._queue)):
@@ -303,15 +322,18 @@ class FleetService:
                     f"{pool} lane {lane}")
 
     def step(self) -> None:
-        """One control round + one engine slice across both pools."""
-        self._harvest()
-        self._recover()
-        self._resume_parked()
-        self._shed()
-        self._evict()
-        self._place()
-        self._snapshot()
-        self._advance()
+        """One control round + one engine slice across both pools, each
+        phase inside a ``service.<phase>`` span (``telemetry``)."""
+        for name, phase in (("service.harvest", self._harvest),
+                            ("service.recover", self._recover),
+                            ("service.resume", self._resume_parked),
+                            ("service.shed", self._shed),
+                            ("service.evict", self._evict),
+                            ("service.place", self._place),
+                            ("service.snapshot", self._snapshot),
+                            ("service.advance", self._advance)):
+            with telemetry.span(name):
+                phase()
         self._slices += 1
 
     def drain(self, max_slices: int = 4000) -> bool:
@@ -362,6 +384,15 @@ class FleetService:
     def _mailbox_w(self, slot: int) -> int:
         return (self._lay.guest_res + 8 * slot) >> 3
 
+    def _read_back(self, *arrays) -> List[np.ndarray]:
+        """Host copies of device arrays; adds each array's bytes to
+        ``stats["readback_bytes"]`` the first time it is read."""
+        for a in arrays:
+            if self._read.get(id(a)) is not a:
+                self._read[id(a)] = a
+                self.stats["readback_bytes"] += a.nbytes
+        return [np.asarray(a) for a in arrays]
+
     def _lane_path(self, pool: str, lane: int) -> str:
         return os.path.join(self._snapshot_dir, f"{pool}-lane{lane}.npz")
 
@@ -375,8 +406,7 @@ class FleetService:
         back to the vacant pool.  Already-DONE jobs are never touched, so
         a recovery replay cannot un-complete work."""
         harts = self._pod.harts.unwrap()
-        mem = np.asarray(harts.mem)
-        hart_done = np.asarray(harts.counters.done)
+        mem, hart_done = self._read_back(harts.mem, harts.counters.done)
         for lane, lst in enumerate(self._pod_lanes):
             if not lst.active:
                 continue
@@ -399,8 +429,8 @@ class FleetService:
         if self._solo is None:
             return
         sh = self._solo.harts.unwrap()
-        s_done = np.asarray(sh.counters.done)
-        s_exit = np.asarray(sh.counters.exit_code)
+        s_done, s_exit = self._read_back(sh.counters.done,
+                                         sh.counters.exit_code)
         for lane, lst in enumerate(self._solo_lanes):
             if not lst.active or not bool(s_done[lane]):
                 continue
@@ -415,6 +445,7 @@ class FleetService:
     def _finish(self, job: Job, checksum: int) -> None:
         job.state = DONE
         job.done_slice = self._slices
+        job.t_done = time.perf_counter()
         job.checksum = checksum
         job.ok = checksum_ok(checksum, job.golden)
         job.lane = None
@@ -436,7 +467,8 @@ class FleetService:
                  self._solo_ran)):
             if fleet is None or not ran:
                 continue
-            instret = np.asarray(fleet.harts.unwrap().counters.instret)
+            instret, = self._read_back(
+                fleet.harts.unwrap().counters.instret)
             for lane, lst in enumerate(lanes):
                 if not lst.active:
                     continue
@@ -496,7 +528,7 @@ class FleetService:
         """Healthy active pod lanes as policy views.  A slot is free when
         no job maps to it and its guest info block reads done (never
         scheduled again until something is spliced in)."""
-        mem = np.asarray(self._pod.harts.unwrap().mem)
+        mem, = self._read_back(self._pod.harts.unwrap().mem)
         views = []
         for lane, lst in enumerate(self._pod_lanes):
             if not lst.active or self._pod_mon.suspect(lane):
@@ -631,6 +663,7 @@ class FleetService:
             self._queue.remove(jid)
             job.state = RUNNING
             job.start_slice = self._slices
+            job.t_start = time.perf_counter()
             job.lane = lane
             job.events.append(f"s{self._slices}: placed on solo "
                               f"lane {lane} ({job.mode})")
@@ -664,6 +697,7 @@ class FleetService:
             self._queue.remove(jid)
             job.state = RUNNING
             job.start_slice = self._slices
+            job.t_start = time.perf_counter()
             job.lane, job.slot = lane, slot
             job.events.append(
                 f"s{self._slices}: placed on lane {lane} slot {slot}")
@@ -686,8 +720,10 @@ class FleetService:
                     continue
                 if lane not in dirty and not periodic:
                     continue
+                state = fleet[lane]
+                self._read_back(*jax.tree.leaves(state))
                 _checkpoint.save(self._lane_path(pool, lane),
-                                 fleet[lane], [fleet.specs[lane]],
+                                 state, [fleet.specs[lane]],
                                  engine_name=getattr(fleet.engine, "name",
                                                      "custom"))
             dirty.clear()

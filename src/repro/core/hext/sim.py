@@ -44,6 +44,7 @@ import numpy as np
 
 from repro.core.hext import engine as _engine
 from repro.core.hext import machine as _machine
+from repro.core.hext import telemetry
 from repro.core.hext.bits import x64
 
 U64 = jnp.uint64
@@ -186,10 +187,11 @@ class HartState:
         """State with a full bootable system image for `workload` loaded
         (native M→S stack, or M→HS xvisor-lite→VS when ``guest``)."""
         from repro.core.hext import programs
-        image = programs.build_image(workload, guest)
-        with x64():
-            st = cls.fresh(programs.MEM_WORDS)
-            return st.with_mem(jnp.asarray(image))
+        with telemetry.span("image.build"):
+            image = programs.build_image(workload, guest)
+            with x64():
+                st = cls.fresh(programs.MEM_WORDS)
+                return st.with_mem(jnp.asarray(image))
 
     @classmethod
     def boot_preemptive(cls, *workloads,
@@ -201,10 +203,11 @@ class HartState:
         from repro.core.hext import programs
         ts = programs.DEFAULT_TIMESLICE if timeslice is None else \
             int(timeslice)
-        image = programs.build_image_nguest(workloads, timeslice=ts)
-        with x64():
-            st = cls.fresh(int(image.shape[0]))
-            return st.with_mem(jnp.asarray(image))
+        with telemetry.span("image.build"):
+            image = programs.build_image_nguest(workloads, timeslice=ts)
+            with x64():
+                st = cls.fresh(int(image.shape[0]))
+                return st.with_mem(jnp.asarray(image))
 
     # -- raw-dict bridge (legacy ISA-core layout) ---------------------------
     @classmethod
@@ -791,7 +794,7 @@ class Fleet:
         """
         if not (0 <= i < len(self._specs)):
             raise ValueError(f"hart {i} out of range")
-        with x64():
+        with telemetry.span("fleet.splice"), x64():
             want = tuple(self._harts.mem.shape[1:])
             got = tuple(jnp.shape(state.mem))
             if got != want:

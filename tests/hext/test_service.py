@@ -30,7 +30,7 @@ import pathlib
 import numpy as np
 import pytest
 
-from repro.core.hext import checkpoint, programs
+from repro.core.hext import checkpoint, programs, telemetry
 from repro.core.hext.policies import (BinPackPolicy, JobView, LaneView,
                                       size_bucket, workload_footprint)
 from repro.core.hext.service import (DONE, QUEUED, REJECTED,
@@ -331,6 +331,68 @@ def test_stragglers_surface_stalled_lanes(tmp_path):
     svc.step()
     svc.step()
     assert ("pod", 0, svc._pod_mon.stall[0]) in svc.stragglers()
+
+
+# ---------------------------------------------------------------------------
+# telemetry: phase spans, read-back bytes, per-job host-clock times
+# ---------------------------------------------------------------------------
+
+PHASES = ["service.harvest", "service.recover", "service.resume",
+          "service.shed", "service.evict", "service.place",
+          "service.snapshot", "service.advance"]
+
+
+@pytest.fixture(scope="module")
+def served(tmp_path_factory):
+    """One cohort (qsort + bitcount) served to the end: round 0 (which
+    provisions lane 0) recorded by a ``telemetry.Recorder``, and the
+    read-back counter before and after round 1."""
+    svc = _svc(tmp_path_factory.mktemp("telemetry"))
+    ids = [svc.submit(BY_NAME[n], tenant=t)
+           for t, n in enumerate(["qsort", "bitcount"])]
+    rec = telemetry.Recorder()
+    telemetry.install(rec)
+    try:
+        svc.step()
+    finally:
+        telemetry.install(None)
+    before = svc.stats["readback_bytes"]
+    svc.step()
+    after = svc.stats["readback_bytes"]
+    assert svc.drain(200)
+    return svc, ids, rec, (before, after)
+
+
+def test_step_records_each_phase_in_order(served):
+    svc, _, rec, _ = served
+    phases = sorted((it for it in rec.items if it[0].startswith("service.")),
+                    key=lambda it: it[1])
+    assert [n for n, _, _ in phases] == PHASES
+    assert all(a[2] <= b[1] for a, b in zip(phases, phases[1:]))
+    _, lo, hi = phases[PHASES.index("service.place")]
+    nested = [it for it in rec.items if not it[0].startswith("service.")]
+    # provisioning lane 0 builds the cohort's image and splices it in
+    assert sorted(n for n, _, _ in nested) == ["fleet.splice", "image.build"]
+    assert all(lo <= a <= b <= hi for _, a, b in nested)
+
+
+def test_job_host_clock_times_are_ordered(served):
+    svc, ids, _, _ = served
+    for i in ids:
+        job = svc.job(i)
+        assert job.state == DONE and job.ok
+        assert job.t_submit <= job.t_start <= job.t_done
+
+
+def test_readback_bytes_count_each_pool_array_once(served):
+    """Round 1 reads the pod's memory (harvest, and again for the shed
+    policy's lane views: the same array), its done flags and its instret;
+    it provisions and snapshots nothing."""
+    svc, _, _, (before, after) = served
+    harts = svc._pod.harts.unwrap()
+    assert after - before == (harts.mem.nbytes + harts.counters.done.nbytes
+                              + harts.counters.instret.nbytes)
+    assert svc.stats["readback_bytes"] > after
 
 
 # ---------------------------------------------------------------------------
