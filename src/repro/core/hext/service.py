@@ -26,7 +26,11 @@ engine runs, interleaving one control round per slice:
   checkpoint (``Fleet.park_guest``) when the queue is starved of lanes;
 * **place** boots policy-chosen cohorts onto vacant lanes — lanes keep
   the pool's compiled shapes (``Fleet.replace_hart``), so the control
-  plane never triggers an XLA recompile after warmup.
+  plane never triggers an XLA recompile after warmup;
+* **snapshot** writes one atomic, fsync'd ``.npz`` per lane a mutation
+  dirtied this round, and per active lane every ``snapshot_every``
+  rounds; a pool that saves any lane is read back once, whole, and each
+  lane is cut from the host copies, all before the engine slice runs.
 
 Lanes never host mid-flight *new* arrivals: cohorts are formed at
 provision time only (the HS scheduler initializes contexts at boot), so
@@ -42,8 +46,9 @@ retry-with-restore supervisor loop became the recover phase here);
 Each phase of a round runs inside a ``service.<phase>`` span
 (``telemetry``, recorded only where a sink is installed);
 ``stats["readback_bytes"]`` counts the device bytes the control plane
-reads back, and each ``Job`` carries host-clock submit, start and done
-times (DESIGN.md §8e).
+reads back, ``stats["snapshot_reads"]`` and ``stats["snapshot_lanes"]``
+the snapshot phase's pool reads and lane files, and each ``Job`` carries
+host-clock submit, start and done times (DESIGN.md §8e).
 """
 from __future__ import annotations
 
@@ -224,7 +229,8 @@ class FleetService:
         self.stats = {"submitted": 0, "rejected": 0, "completed": 0,
                       "failed": 0, "migrations": 0, "parks": 0,
                       "resumes": 0, "recoveries": 0, "balloons": 0,
-                      "readback_bytes": 0}
+                      "readback_bytes": 0, "snapshot_reads": 0,
+                      "snapshot_lanes": 0}
         # device arrays already counted in readback_bytes, by id: JAX
         # serves a second np.asarray of one array from its host copy
         self._read = weakref.WeakValueDictionary()
@@ -706,7 +712,9 @@ class FleetService:
         """Write per-lane snapshots: every lane a control-plane mutation
         dirtied this round, plus a periodic refresh.  Suspect lanes are
         skipped, so the newest file for a lane always predates its
-        failure."""
+        failure.  A pool that saves any lane is read back once, whole,
+        and each lane is cut from the host copies; every file is fsync'd
+        and in place before the round's engine slice starts."""
         periodic = (self._slices % self.snapshot_every) == 0
         for pool, fleet, lanes, mon, dirty in (
                 ("pod", self._pod, self._pod_lanes, self._pod_mon,
@@ -715,17 +723,21 @@ class FleetService:
                  self._dirty_solo)):
             if fleet is None:
                 continue
-            for lane, lst in enumerate(lanes):
-                if not lst.active or mon.suspect(lane):
-                    continue
-                if lane not in dirty and not periodic:
-                    continue
-                state = fleet[lane]
-                self._read_back(*jax.tree.leaves(state))
-                _checkpoint.save(self._lane_path(pool, lane),
-                                 state, [fleet.specs[lane]],
-                                 engine_name=getattr(fleet.engine, "name",
-                                                     "custom"))
+            save = [lane for lane, lst in enumerate(lanes)
+                    if lst.active and not mon.suspect(lane)
+                    and (periodic or lane in dirty)]
+            if save:
+                leaves, treedef = jax.tree.flatten(fleet.harts.unwrap())
+                host = self._read_back(*leaves)
+                self.stats["snapshot_reads"] += 1
+                specs = fleet.specs
+                engine_name = getattr(fleet.engine, "name", "custom")
+                for lane in save:
+                    state = jax.tree.unflatten(treedef,
+                                               [x[lane] for x in host])
+                    _checkpoint.save(self._lane_path(pool, lane), state,
+                                     [specs[lane]], engine_name=engine_name)
+                self.stats["snapshot_lanes"] += len(save)
             dirty.clear()
 
     def _advance(self) -> None:

@@ -14,7 +14,9 @@ Quick tests (CI push gate, ``-m serve`` selects the family):
   pressure,
 * migration-based shed preserves goldens (N=3 pod),
 * an injected hart failure (pod and solo) recovers from the last
-  per-lane snapshot with zero lost completed work.
+  per-lane snapshot with zero lost completed work,
+* per-lane snapshots cut from one read of each pool equal saves of the
+  device slice, and a round that saves nothing reads nothing.
 
 Slow tests (nightly): a seeded 64-submission open-loop soak with a
 mid-soak hart failure — every checksum matches the registry goldens.
@@ -393,6 +395,115 @@ def test_readback_bytes_count_each_pool_array_once(served):
     assert after - before == (harts.mem.nbytes + harts.counters.done.nbytes
                               + harts.counters.instret.nbytes)
     assert svc.stats["readback_bytes"] > after
+
+
+# ---------------------------------------------------------------------------
+# snapshots: one read of each pool, lanes cut on the host
+# ---------------------------------------------------------------------------
+
+def _load_npz(path):
+    with np.load(path) as z:
+        return {k: z[k] for k in z.files}
+
+
+@pytest.fixture(scope="module")
+def snapshot_rounds(tmp_path_factory):
+    """Four rounds of a pod + solo service (``snapshot_every=3``): round 0
+    provisions pod lane 0 and solo lane 0 (periodic), round 1 pod lane 1
+    and solo lane 1 (dirty only), round 3 is periodic.  Around each
+    ``_snapshot``: the lanes the rule selects, the files written, the
+    counters' moves, and each file beside what ``checkpoint.save`` writes
+    from the device slice ``fleet[lane]`` at the same point."""
+    tmp = tmp_path_factory.mktemp("snapshots")
+    svc = _svc(tmp, n_solo=2, snapshot_every=3)
+    svc.submit(BY_NAME["qsort"], tenant=0)
+    svc.submit(BY_NAME["bitcount"], tenant=1)
+    svc.submit(BY_NAME["dijkstra"], tenant=2, mode="native")
+    real, orig_save = svc._snapshot, checkpoint.save
+    rounds = []
+
+    def snapshot():
+        pools = {"pod": (svc._pod, svc._pod_lanes, svc._pod_mon,
+                         svc._dirty_pod),
+                 "solo": (svc._solo, svc._solo_lanes, svc._solo_mon,
+                          svc._dirty_solo)}
+        periodic = svc.slices % svc.snapshot_every == 0
+        want = {pool: sorted(
+            lane for lane, lst in enumerate(lanes)
+            if lst.active and not mon.suspect(lane)
+            and (periodic or lane in dirty))
+            for pool, (_, lanes, mon, dirty) in pools.items()}
+        saved = []
+
+        def save(path, *args, **kw):
+            saved.append(os.path.basename(path))
+            return orig_save(path, *args, **kw)
+
+        before = dict(svc.stats)
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(checkpoint, "save", save)
+            real()
+        moved = {k: svc.stats[k] - before[k] for k in
+                 ("snapshot_reads", "snapshot_lanes", "readback_bytes")}
+        pairs = []
+        for name in saved:
+            pool, lane = name[:-len(".npz")].split("-lane")
+            fleet = pools[pool][0]
+            ref = str(tmp / f"ref-{svc.slices}-{name}")
+            orig_save(ref, fleet[int(lane)], [fleet.specs[int(lane)]],
+                      engine_name=getattr(fleet.engine, "name", "custom"))
+            pairs.append((name, _load_npz(os.path.join(svc._snapshot_dir,
+                                                       name)),
+                          _load_npz(ref)))
+        rounds.append({"periodic": periodic, "want": want, "saved": saved,
+                       "moved": moved, "pairs": pairs})
+
+    svc._snapshot = snapshot
+    svc.step()
+    svc.submit(BY_NAME["susan"], tenant=3)
+    svc.submit(BY_NAME["crc32"], tenant=4)
+    svc.submit(BY_NAME["dijkstra"], tenant=5, mode="native")
+    for _ in range(3):
+        svc.step()
+    return rounds
+
+
+def test_snapshot_files_equal_device_slice_saves(snapshot_rounds):
+    """The lanes the rule selects are saved, and each file holds, array by
+    array with dtypes and ``__meta__``, what saving the device slice
+    writes: pod and solo lanes, in periodic and in dirty-only rounds."""
+    r0, r1, _, r3 = snapshot_rounds
+    assert (r0["periodic"], r1["periodic"], r3["periodic"]) == \
+        (True, False, True)
+    assert r0["want"] == {"pod": [0], "solo": [0]}
+    assert r1["want"] == {"pod": [1], "solo": [1]}
+    assert r3["want"] == {"pod": [0, 1], "solo": [0, 1]}
+    for r in snapshot_rounds:
+        assert sorted(r["saved"]) == sorted(
+            f"{pool}-lane{lane}.npz"
+            for pool, lanes in r["want"].items() for lane in lanes)
+        for name, got, ref in r["pairs"]:
+            assert sorted(got) == sorted(ref), name
+            for key in ref:
+                assert got[key].dtype == ref[key].dtype, (name, key)
+                assert got[key].shape == ref[key].shape, (name, key)
+                np.testing.assert_array_equal(got[key], ref[key],
+                                              err_msg=f"{name} {key}")
+
+
+def test_snapshot_reads_each_saving_pool_once(snapshot_rounds):
+    """A round that saves lanes reads each pool that saves any once and
+    counts one file per lane; a round that saves nothing reads nothing."""
+    idle = 0
+    for r in snapshot_rounds:
+        pools = sum(bool(lanes) for lanes in r["want"].values())
+        lanes = sum(len(v) for v in r["want"].values())
+        assert r["moved"]["snapshot_reads"] == pools
+        assert r["moved"]["snapshot_lanes"] == lanes == len(r["saved"])
+        if not lanes:
+            idle += 1
+            assert r["moved"]["readback_bytes"] == 0
+    assert idle >= 1
 
 
 # ---------------------------------------------------------------------------
