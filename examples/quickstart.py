@@ -2,10 +2,10 @@
 it against native execution — the paper's experiment in 30 lines.
 
 The optional second argument picks the execution backend (DESIGN.md §3):
-``jit`` (default), ``sharded`` (pmap over jax.devices()), or ``oracle``
-(the pure-Python reference model — slow, but great for differential
-debugging: every counter, `walks` included, matches the device engines
-bit-for-bit).
+``jit`` (default), ``sharded`` (shard_map over a mesh of jax.devices()),
+or ``oracle`` (the pure-Python reference model — slow, but great for
+differential debugging: every counter, `walks` included, matches the
+device engines bit-for-bit).
 
 Run with the package on the path (see DESIGN.md §6):
 

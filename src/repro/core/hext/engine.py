@@ -3,18 +3,21 @@
 gem5 exposes swappable CPU models behind one plug point; this module is
 the same seam for the hext fleet.  An :class:`Engine` advances a (possibly
 batched) ``HartState`` by up to ``max_ticks`` ticks and returns the final
-state — everything else about *how* (one jitted while-loop, a pmap across
-devices, a pure-Python interpreter) is backend-private.  Three backends
-are registered:
+state — everything else about *how* (one jitted while-loop, a shard_map
+over a mesh of devices, a pure-Python interpreter) is backend-private.
+Three backends are registered:
 
 * ``"jit"`` — :class:`JitEngine`, the donated on-device ``lax.while_loop``
   over chunked scans (the engine ``Fleet`` always used; extracted here
   from ``sim.run_on_device``).
-* ``"sharded"`` — :class:`ShardedEngine`, ``jax.pmap`` over
-  ``jax.devices()`` with the fleet padded to a device multiple.  Each
-  device runs the same while-loop on its shard, so per-hart results are
-  bit-identical to ``"jit"``.  On a single device it falls back to
-  :class:`JitEngine` (same executable, no pmap overhead).
+* ``"sharded"`` — :class:`ShardedEngine`, one ``jax.jit`` of
+  ``jax.shard_map`` over a 1-D ``Mesh`` of ``jax.devices()`` (or the
+  devices given), the state donated.  Each device runs the same
+  while-loop on its shard and exits it on its own, so per-hart results
+  are bit-identical to ``"jit"``.  A batch built on the mesh
+  (``ShardedEngine.sharding``) runs where it lies; any other is padded to
+  a device multiple and laid out once.  On a single device it falls back
+  to :class:`JitEngine`.
 * ``"oracle"`` — :class:`OracleEngine`, the pure-Python architectural
   oracle (``repro.core.hext.oracle``) behind the same typed interface.
   This makes differential runs first-class: boot the same workloads twice
@@ -42,10 +45,12 @@ from typing import Any, Callable, Dict, List, Optional, Protocol, \
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.hext import csr as C
 from repro.core.hext import machine as _machine
 from repro.core.hext import oracle as _oracle
+from repro.core.hext import telemetry
 from repro.core.hext.bits import x64
 
 U64 = jnp.uint64
@@ -96,10 +101,11 @@ def _is_batched(state) -> bool:
 # ShardedEngine): while_loop over chunked scans, gated on all(done)
 # ---------------------------------------------------------------------------
 
-def _run_impl(state, n_chunks, chunk: int, ips: int = 1):
-    """`n_chunks` chunk-scans max, early exit once every hart reports done
-    (no per-chunk host sync).  Only `chunk`/`ips` are static — different
-    tick budgets reuse the same executable.
+def _run_loop(state, n_chunks, chunk: int, ips: int = 1):
+    """``(state, chunk-scans run)``: `n_chunks` chunk-scans max, early exit
+    once every hart reports done (no per-chunk host sync).  Only
+    `chunk`/`ips` are static — different tick budgets reuse the same
+    executable.
 
     A batched state runs ``machine.step_batched`` directly: the pipeline's
     batch-level ``lax.cond`` fast paths (walk skip, SYSTEM skip, trap
@@ -135,9 +141,13 @@ def _run_impl(state, n_chunks, chunk: int, ips: int = 1):
         s = jax.lax.scan(scan_body, s, None, length=chunk // ips)[0]
         return s, i + jnp.ones((), jnp.int32)
 
-    state, _ = jax.lax.while_loop(cond, body,
-                                  (state, jnp.zeros((), jnp.int32)))
-    return state
+    return jax.lax.while_loop(cond, body, (state, jnp.zeros((), jnp.int32)))
+
+
+def _run_impl(state, n_chunks, chunk: int, ips: int = 1):
+    """The run loop's final state (:func:`_run_loop` without its count of
+    chunk-scans run)."""
+    return _run_loop(state, n_chunks, chunk, ips)[0]
 
 
 def _check_ips(chunk: int, ips: int) -> int:
@@ -229,66 +239,97 @@ class JitEngine:
 
 
 # ---------------------------------------------------------------------------
-# ShardedEngine — pmap over jax.devices() with fleet padding
+# ShardedEngine — shard_map over a 1-D mesh of harts
 # ---------------------------------------------------------------------------
 
-_pmap_cache: Dict[Any, Any] = {}
+HARTS = "harts"
 
 
-def _pmap_fn(chunk: int, devices: tuple, ips: int = 1):
-    key = (chunk, devices, ips)
-    fn = _pmap_cache.get(key)
-    if fn is None:
-        fn = jax.pmap(_run_impl, in_axes=(0, None),
-                      static_broadcasted_argnums=(2, 3),
-                      devices=list(devices))
-        _pmap_cache[key] = fn
-    return fn
+def _run_sharded_impl(state, n_chunks, mesh: Mesh, chunk: int, ips: int):
+    """The run loop on each shard of ``mesh``: each shard exits its own
+    while-loop once its harts are done (no collective per chunk), and
+    returns its loop counter beside its state."""
+    def shard(s, n):
+        s, i = _run_loop(s, n, chunk, ips)
+        return s, i[None]
+    return jax.shard_map(shard, mesh=mesh, in_specs=(P(HARTS), P()),
+                         out_specs=(P(HARTS), P(HARTS)),
+                         check_vma=False)(state, n_chunks)
+
+
+_run_sharded = jax.jit(_run_sharded_impl, static_argnums=(2, 3, 4),
+                       donate_argnums=(0,))
 
 
 class ShardedEngine:
-    """Data-parallel backend: shard the hart batch across devices.
+    """Data-parallel backend: shard the hart batch over a 1-D ``Mesh`` of
+    devices (axis ``"harts"``) and run the same while-loop on each shard
+    under ``jax.shard_map``, the state donated as :class:`JitEngine`
+    donates it.  Harts are fully independent, so counters are
+    bit-identical to :class:`JitEngine`, hart for hart.
 
-    The fleet is padded up to a device multiple by repeating harts with
-    ``done=True`` (frozen by ``machine.step``, and invisible to each
-    shard's ``all(done)`` early exit), reshaped to a leading device axis,
-    and run through the same while-loop per device.  Harts are fully
-    independent, so counters are bit-identical to :class:`JitEngine`.
+    A state that already lies on the mesh with :attr:`sharding` and
+    divides evenly goes straight in and comes out on the mesh.  Any other
+    is laid out once, inside an ``engine.place`` span: padded up to a
+    device multiple by repeating harts with ``done=True`` (frozen by
+    ``machine.step``, and invisible to each shard's ``all(done)`` early
+    exit) and moved onto the mesh; the padding is sliced off the result.
 
-    On a single device (or an unbatched state) this falls back to
-    :class:`JitEngine` — same compiled executable, no pmap dispatch."""
+    ``stats["place_bytes"]`` counts the bytes laid out so;
+    ``stats["shard_chunks"]`` the chunk-scans each shard ran, summed over
+    runs.  On a single device (or an unbatched state) this falls back to
+    :class:`JitEngine`."""
 
     name = "sharded"
 
     def __init__(self, devices: Optional[list] = None,
                  instrs_per_step: int = 1):
-        self._devices = devices
+        devs = list(jax.devices() if devices is None else devices)
         self._ips = int(instrs_per_step)
+        self.mesh = Mesh(np.array(devs), (HARTS,))
+        self.sharding = NamedSharding(self.mesh, P(HARTS))
+        self.stats = {"place_bytes": 0, "shard_chunks": [0] * len(devs)}
 
-    def run(self, state, max_ticks: int, chunk: int = 4096):
-        ips = _check_ips(chunk, self._ips)
-        devs = tuple(self._devices if self._devices is not None
-                     else jax.devices())
-        if not _is_batched(state) or len(devs) < 2:
-            return JitEngine(instrs_per_step=ips).run(state, max_ticks,
-                                                      chunk)
-        with x64():
-            b = int(state.counters.done.shape[0])
-            d = min(len(devs), b)
-            bp = -(-b // d) * d
+    def _place(self, state):
+        """``state`` padded to a device multiple and laid out on the mesh,
+        or as it is where it lies there already."""
+        b = int(state.counters.done.shape[0])
+        bp = -(-b // self.mesh.size) * self.mesh.size
+        if bp == b and all(
+                getattr(x, "sharding", None) is not None and
+                x.sharding.is_equivalent_to(self.sharding, x.ndim)
+                for x in jax.tree.leaves(state)):
+            return state
+        with telemetry.span("engine.place"):
             if bp != b:
                 idx = np.arange(bp) % b               # repeat to pad
                 state = jax.tree.map(lambda x: x[idx], state)
                 done = state.counters.done.at[b:].set(True)
                 state = state.replace(counters=dataclasses.replace(
                     state.counters, done=done))
-            sharded = jax.tree.map(
-                lambda x: x.reshape((d, bp // d) + x.shape[1:]), state)
-            out = _pmap_fn(int(chunk), devs[:d], ips)(
-                sharded, jnp.asarray(_n_chunks(max_ticks, chunk),
-                                     jnp.int32), int(chunk), ips)
-            out = jax.tree.map(
-                lambda x: x.reshape((bp,) + x.shape[2:])[:b], out)
+            self.stats["place_bytes"] += sum(
+                int(x.nbytes) for x in jax.tree.leaves(state))
+            return jax.device_put(state, self.sharding)
+
+    def run(self, state, max_ticks: int, chunk: int = 4096):
+        ips = _check_ips(chunk, self._ips)
+        if not _is_batched(state) or self.mesh.size < 2:
+            return JitEngine(instrs_per_step=ips).run(state, max_ticks,
+                                                      chunk)
+        with x64(), warnings.catch_warnings():
+            if jax.default_backend() == "cpu":
+                warnings.filterwarnings(
+                    "ignore", message=".*[Dd]onat.*", category=UserWarning)
+            b = int(state.counters.done.shape[0])
+            out, chunks = _run_sharded(
+                self._place(state), jnp.asarray(_n_chunks(max_ticks, chunk),
+                                                jnp.int32),
+                self.mesh, int(chunk), ips)
+            if int(out.counters.done.shape[0]) != b:
+                out = jax.tree.map(lambda x: x[:b], out)
+            self.stats["shard_chunks"] = [
+                a + int(c) for a, c in zip(self.stats["shard_chunks"],
+                                           np.asarray(chunks))]
             return jax.block_until_ready(out)
 
 

@@ -22,8 +22,8 @@ objects:
 Execution is delegated to a pluggable :mod:`repro.core.hext.engine`
 backend (``Fleet.boot(..., engine="jit"|"sharded"|"oracle")``): the
 default ``JitEngine`` runs the donated on-device ``lax.while_loop`` over
-chunked scans, ``ShardedEngine`` pmaps the batch across ``jax.devices()``,
-and ``OracleEngine`` drives the pure-Python reference model behind the
+chunked scans, ``ShardedEngine`` runs the same loop on each shard of the
+batch under ``jax.shard_map`` over a mesh of ``jax.devices()``, and ``OracleEngine`` drives the pure-Python reference model behind the
 same typed interface.  On top of the unified state path the fleet offers
 gem5-style checkpointing (``Fleet.snapshot`` / ``Fleet.restore``, a
 versioned ``.npz`` with a schema-hash guard — see

@@ -14,8 +14,10 @@ Slow tests (nightly / full suite):
   bit-identical to the committed `hext_runs.json` goldens,
 * snapshot-resume bit-identity for native, guest, and an N=4 preemptive
   slot,
-* a true multi-device ShardedEngine run (subprocess with forced host
-  devices) matching JitEngine per hart,
+* true multi-device ShardedEngine runs (subprocesses with forced host
+  devices) matching JitEngine per hart: a padded fleet laid out on the
+  mesh, and a native/guest matrix built on the mesh that also matches the
+  oracle's goldens,
 * the live-migration demo: a mid-flight guest moves harts and still hits
   its golden checksum on the destination.
 """
@@ -36,6 +38,7 @@ from repro.core.hext.sim import (Fleet, MigrationError, StaleHartsError,
                                  MASK64, checksum_ok)
 
 REPO = pathlib.Path(__file__).resolve().parents[2]
+GOLDENS = REPO / "bench" / "reference" / "mibench-goldens.json"
 CHUNK = 1024
 
 
@@ -238,7 +241,7 @@ def test_stale_harts_reference_raises():
 def test_sharded_engine_fallback_matches_jit():
     """On a single device ShardedEngine must fall back to the jit path and
     produce identical results (on a forced multi-device host this instead
-    exercises the pmap path — equally required to match)."""
+    exercises the shard_map path — equally required to match)."""
     fj = _boot_sha_pair().run(30000, chunk=CHUNK)
     fs = _boot_sha_pair("sharded").run(30000, chunk=CHUNK)
     for i in range(2):
@@ -262,15 +265,32 @@ def test_instrs_per_step_bit_identical():
         engine._check_ips(CHUNK, 3)       # 1024 % 3 != 0
 
 
+def _run_on_four_devices(script: str) -> str:
+    """Run ``script`` in a child process that sees 4 forced CPU devices
+    (the device count is fixed when JAX starts); returns its stdout."""
+    env = dict(os.environ)
+    # the child must never reach for an accelerator the parent may hold
+    env["JAX_PLATFORMS"] = "cpu"
+    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    env["PYTHONPATH"] = str(REPO / "src")
+    res = subprocess.run([sys.executable, "-c", textwrap.dedent(script)],
+                         env=env, cwd=str(REPO), capture_output=True,
+                         text=True, timeout=600)
+    assert res.returncode == 0, res.stderr[-3000:]
+    return res.stdout
+
+
 @pytest.mark.slow
 def test_sharded_engine_multi_device_matches_jit():
-    """The real pmap path: 4 forced host devices, 6 harts (padding 6→8).
-    Per-hart results must be bit-identical to the jit engine."""
-    script = textwrap.dedent("""
+    """The shard_map path: 4 forced host devices, 6 harts padded to 8 and
+    laid out on the mesh inside an ``engine.place`` span.  Per-hart
+    results must be bit-identical to the jit engine, and the padding must
+    not show in the result."""
+    out = _run_on_four_devices("""
         import numpy as np, jax
         assert len(jax.devices()) == 4, jax.devices()
         from repro.core.hext.sim import Fleet
-        from repro.core.hext import engine, programs
+        from repro.core.hext import engine, programs, telemetry
 
         def img(val):
             a = programs.Asm(0)
@@ -284,24 +304,86 @@ def test_sharded_engine_multi_device_matches_jit():
 
         imgs = [img(100 + i) for i in range(6)]
         fj = Fleet.from_images(imgs, mem_words=256).run(512, chunk=128)
-        fs = Fleet.from_images(imgs, mem_words=256,
-                               engine='sharded').run(512, chunk=128)
+        eng = engine.ShardedEngine()
+        rec = telemetry.Recorder()
+        telemetry.install(rec)
+        try:
+            fs = Fleet.from_images(imgs, mem_words=256,
+                                   engine=eng).run(512, chunk=128)
+        finally:
+            telemetry.install(None)
+        assert len(fs) == 6
         for i in range(6):
             assert engine.diff_states(fs[i], fj[i]) == [], i
             assert int(fs[i].counters.walks) == int(fj[i].counters.walks)
             assert int(fs[i].counters.exit_code) == 100 + i
+        assert [n for n, _, _ in rec.items] == ['engine.place']
+        # 8 rows of the 6-hart state were laid out
+        row = sum(x.nbytes for x in jax.tree.leaves(fj.harts.unwrap())) // 6
+        assert eng.stats['place_bytes'] == 8 * row, eng.stats
+        # the last shard holds the two padding harts: done from the start
+        assert eng.stats['shard_chunks'] == [1, 1, 1, 0], eng.stats
         print('SHARDED-MULTI-OK')
     """)
-    env = dict(os.environ)
-    # the child must never reach for an accelerator the parent may hold
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env["PYTHONPATH"] = str(REPO / "src")
-    res = subprocess.run([sys.executable, "-c", script], env=env,
-                         cwd=str(REPO), capture_output=True, text=True,
-                         timeout=600)
-    assert res.returncode == 0, res.stderr
-    assert "SHARDED-MULTI-OK" in res.stdout
+    assert "SHARDED-MULTI-OK" in out
+
+
+@pytest.mark.slow
+def test_sharded_engine_matrix_on_the_mesh_matches_jit_and_goldens():
+    """A native/guest matrix (sha, fft; 8 harts) built on the mesh with
+    ``ShardedEngine.sharding`` runs twice where it lies: nothing is laid
+    out, its memory stays on all four devices, each shard's chunk-scans
+    are its own harts' count, and the result equals the jit engine's in
+    every field and the oracle's goldens in every counter."""
+    out = _run_on_four_devices(f"""
+        import json
+        import numpy as np, jax
+        from repro.core.hext import engine, programs
+        from repro.core.hext.bits import x64
+        from repro.core.hext.sim import Fleet
+
+        CHUNK = 512
+        ref = json.load(open({str(GOLDENS)!r}))["workloads"]
+        jobs = [(w, g) for g in (False, True)
+                for w in (programs.SHA(), programs.FFT())] * 2
+
+        def boot(eng=None):
+            return Fleet.boot([w for w, _ in jobs],
+                              guest=[g for _, g in jobs], engine=eng)
+
+        fj = boot().run(120000, chunk=CHUNK)
+        eng = engine.ShardedEngine()
+        base = boot()
+        with x64():
+            state = jax.device_put(base.harts.unwrap(), eng.sharding)
+        fs = Fleet(state, base.specs, engine=eng)
+        own = [0] * 4
+        for budget in (2 * CHUNK, 120000):
+            with x64():
+                before = np.asarray(fs.harts.unwrap().counters.ticks)
+            fs.run(budget, chunk=CHUNK)
+            with x64():
+                adv = np.asarray(fs.harts.unwrap().counters.ticks) - before
+            for s in range(4):
+                own[s] += -(-int(adv[2 * s:2 * s + 2].max()) // CHUNK)
+        assert eng.stats["place_bytes"] == 0, eng.stats
+        assert eng.stats["shard_chunks"] == own, (eng.stats, own)
+        with x64():
+            mem = fs.harts.unwrap().mem
+            held = sorted(s.device.id for s in mem.addressable_shards)
+            assert held == [0, 1, 2, 3], held
+            assert mem.sharding.is_equivalent_to(eng.sharding, mem.ndim)
+        a = engine.state_arrays(fs.harts.unwrap())
+        b = engine.state_arrays(fj.harts.unwrap())
+        assert [k for k in a if not np.array_equal(a[k], b[k])] == []
+        for (w, g), c in zip(jobs, fs.counters()):
+            got = c.to_dict()
+            want = ref[w.name]["guest" if g else "native"]
+            assert {{k: got[k] for k in want}} == want, (w.name, g)
+            assert got["exit_code"] == ref[w.name]["checksum"]
+        print("SHARDED-MATRIX-OK", own)
+    """)
+    assert "SHARDED-MATRIX-OK" in out
 
 
 # ---------------------------------------------------------------------------

@@ -1,4 +1,5 @@
-"""Compile the chip's main-path programs for a described TPU v5e.
+"""Compile the chip's main-path programs for a described TPU v5e (one
+chip, or the four of a 2x2 host for the sharded run loop).
 
 Nothing runs: the TPU compiler installed with JAX compiles for a chip that
 is described, not attached, and refuses what the chip would refuse (an
@@ -11,8 +12,10 @@ import os
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, \
+    SingleDeviceSharding
 
 from repro.core.hext import engine, programs
 from repro.core.hext.bits import x64
@@ -38,6 +41,11 @@ def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture(scope="module")
+def mesh(topo):
+    return Mesh(np.array(topo.devices), (engine.HARTS,))
+
+
 def _device_bytes(compiled) -> int:
     m = compiled.memory_analysis()
     return (m.argument_size_in_bytes + m.output_size_in_bytes
@@ -57,6 +65,34 @@ def test_run_loop_compiles_for_v5e_and_fits(one_chip):
         n_chunks = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
         compiled = engine._run_jit_donating.lower(
             shapes, n_chunks, 8192, 1).compile()
+    assert 0 < _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_sharded_run_loop_compiles_for_v5e_2x2_without_collectives(mesh):
+    """``ShardedEngine``'s donated ``shard_map`` loop for 4 copies of the
+    matrix, 18 harts a chip of a 2x2 v5e, as ``Fleet.run`` calls it: each
+    chip exits its own loop, so the program holds no collective, and the
+    hart state is updated in place."""
+    wls = programs.WORKLOADS
+    fleet = Fleet.boot(wls + wls, guest=[False] * len(wls) + [True] * len(wls))
+    harts = NamedSharding(mesh, PartitionSpec(engine.HARTS))
+    with x64():
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct((4 * x.shape[0],) + x.shape[1:],
+                                           x.dtype, sharding=harts),
+            fleet.harts.unwrap())
+        n_chunks = jax.ShapeDtypeStruct(
+            (), jnp.int32, sharding=NamedSharding(mesh, PartitionSpec()))
+        compiled = engine._run_sharded.lower(
+            shapes, n_chunks, mesh, 8192, 1).compile()
+    text = compiled.as_text()
+    for op in ("all-reduce", "all-gather", "all-to-all",
+               "collective-permute", "reduce-scatter"):
+        assert op not in text, op
+    m = compiled.memory_analysis()
+    state_bytes = sum(s.size * s.dtype.itemsize
+                      for s in jax.tree.leaves(shapes)) // 4
+    assert m.alias_size_in_bytes >= state_bytes
     assert 0 < _device_bytes(compiled) < V5E_HBM_BYTES
 
 
