@@ -8,8 +8,9 @@ over a mesh of devices, a pure-Python interpreter) is backend-private.
 Three backends are registered:
 
 * ``"jit"`` — :class:`JitEngine`, the donated on-device ``lax.while_loop``
-  over chunked scans (the engine ``Fleet`` always used; extracted here
-  from ``sim.run_on_device``).
+  over short scans (the engine ``Fleet`` always used; extracted here
+  from ``sim.run_on_device``).  The tick budget is whole chunks; the
+  loop checks ``all(done)`` every :func:`_block` ticks and exits there.
 * ``"sharded"`` — :class:`ShardedEngine`, one ``jax.jit`` of
   ``jax.shard_map`` over a 1-D ``Mesh`` of ``jax.devices()`` (or the
   devices given), the state donated.  Each device runs the same
@@ -36,6 +37,7 @@ All entry points own the x64 context, like the facade they serve.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import pathlib
 import warnings
@@ -96,16 +98,27 @@ def _is_batched(state) -> bool:
     return state.counters.done.ndim == 1
 
 
+def _block(chunk: int, ips: int) -> int:
+    """Ticks between two of the run loop's ``all(done)`` checks: ``ips``
+    times the largest divisor of ``chunk // ips`` that divides 64, so it
+    divides ``chunk`` (64 for a power-of-two chunk of 64 or more at
+    ``ips=1``)."""
+    return ips * math.gcd(chunk // ips, 64)
+
+
 # ---------------------------------------------------------------------------
 # the shared on-device run loop (used by JitEngine and, per shard, by
-# ShardedEngine): while_loop over chunked scans, gated on all(done)
+# ShardedEngine): while_loop over short scans, gated on all(done)
 # ---------------------------------------------------------------------------
 
 def _run_loop(state, n_chunks, chunk: int, ips: int = 1):
-    """``(state, chunk-scans run)``: `n_chunks` chunk-scans max, early exit
-    once every hart reports done (no per-chunk host sync).  Only
-    `chunk`/`ips` are static — different tick budgets reuse the same
-    executable.
+    """``(state, blocks run)``: at most ``n_chunks * chunk`` ticks, in
+    blocks of :func:`_block` ticks, with an exit once every hart reports
+    done (checked on device after each block, no host sync).  The budget
+    is still whole chunks, so a hart that never finishes advances exactly
+    ``n_chunks * chunk`` ticks; the block only sets how soon the loop
+    notices that every hart is done.  Only `chunk`/`ips` are static —
+    different tick budgets reuse the same executable.
 
     A batched state runs ``machine.step_batched`` directly: the pipeline's
     batch-level ``lax.cond`` fast paths (walk skip, SYSTEM skip, trap
@@ -114,11 +127,11 @@ def _run_loop(state, n_chunks, chunk: int, ips: int = 1):
     back the cost the pipeline removed.
 
     ``ips`` (instrs_per_step) unrolls that many architectural ticks into
-    one scan element, shrinking the scan to ``chunk // ips`` elements —
-    less per-element scan/dispatch overhead at the price of a bigger
-    step graph.  Tick semantics are unchanged (each chunk-scan still
-    advances exactly ``chunk`` ticks); results are bit-identical by
-    construction because the unrolled body is the same step composed."""
+    one scan element, shrinking each block's scan to ``block // ips``
+    elements — less per-element scan/dispatch overhead at the price of a
+    bigger step graph.  Tick semantics are unchanged: done harts are
+    frozen by the step, so where the loop exits changes no result."""
+    block = _block(chunk, ips)
     batched = _is_batched(state)
     if batched:
         def step_fn(s):
@@ -133,21 +146,23 @@ def _run_loop(state, n_chunks, chunk: int, ips: int = 1):
         return s, None
 
     def cond(carry):
+        # i < n_chunks * blocks-a-chunk, in a form whose product cannot
+        # overflow the int32 counter for any budget that fits n_chunks
         s, i = carry
-        return (i < n_chunks) & ~jnp.all(s.counters.done)
+        return (i // (chunk // block) < n_chunks) & ~jnp.all(s.counters.done)
 
     def body(carry):
         s, i = carry
-        s = jax.lax.scan(scan_body, s, None, length=chunk // ips)[0]
+        s = jax.lax.scan(scan_body, s, None, length=block // ips)[0]
         return s, i + jnp.ones((), jnp.int32)
 
     return jax.lax.while_loop(cond, body, (state, jnp.zeros((), jnp.int32)))
 
 
 def _run_impl(state, n_chunks, chunk: int, ips: int = 1):
-    """The run loop's final state (:func:`_run_loop` without its count of
-    chunk-scans run)."""
-    return _run_loop(state, n_chunks, chunk, ips)[0]
+    """:func:`_run_loop` under the name that labels the jitted program's
+    ops (``jit(_run_impl)/while/body/...``, read by the stage reduction)."""
+    return _run_loop(state, n_chunks, chunk, ips)
 
 
 def _check_ips(chunk: int, ips: int) -> int:
@@ -216,13 +231,18 @@ class JitEngine:
     With ``donate`` (Fleet's mode) the input buffers are donated and
     updated in place, so the input state must not be reused after `run`;
     ``donate=False`` serves callers that keep a reference to the input
-    (the `run_on_device` compat wrapper exposes this)."""
+    (the `run_on_device` compat wrapper exposes this).
+
+    ``stats["loop_ticks"]`` counts the loop ticks executed, summed over
+    runs: the longest advance rounded up to the loop's block (not to the
+    chunk), or the whole budget where some hart is not done."""
 
     name = "jit"
 
     def __init__(self, donate: bool = True, instrs_per_step: int = 1):
         self._donate = donate
         self._ips = int(instrs_per_step)
+        self.stats = {"loop_ticks": 0}
 
     def run(self, state, max_ticks: int, chunk: int = 4096):
         ips = _check_ips(chunk, self._ips)
@@ -233,9 +253,11 @@ class JitEngine:
             if jax.default_backend() == "cpu":
                 warnings.filterwarnings(
                     "ignore", message=".*[Dd]onat.*", category=UserWarning)
-            out = fn(state, jnp.asarray(_n_chunks(max_ticks, chunk),
-                                        jnp.int32), int(chunk), ips)
-            return jax.block_until_ready(out)
+            out, blocks = fn(state, jnp.asarray(_n_chunks(max_ticks, chunk),
+                                                jnp.int32), int(chunk), ips)
+            out = jax.block_until_ready(out)
+            self.stats["loop_ticks"] += int(blocks) * _block(int(chunk), ips)
+            return out
 
 
 # ---------------------------------------------------------------------------
@@ -247,8 +269,8 @@ HARTS = "harts"
 
 def _run_sharded_impl(state, n_chunks, mesh: Mesh, chunk: int, ips: int):
     """The run loop on each shard of ``mesh``: each shard exits its own
-    while-loop once its harts are done (no collective per chunk), and
-    returns its loop counter beside its state."""
+    while-loop once its harts are done (no collective per block), and
+    returns its count of blocks run beside its state."""
     def shard(s, n):
         s, i = _run_loop(s, n, chunk, ips)
         return s, i[None]
@@ -276,9 +298,10 @@ class ShardedEngine:
     exit) and moved onto the mesh; the padding is sliced off the result.
 
     ``stats["place_bytes"]`` counts the bytes laid out so;
-    ``stats["shard_chunks"]`` the chunk-scans each shard ran, summed over
-    runs.  On a single device (or an unbatched state) this falls back to
-    :class:`JitEngine`."""
+    ``stats["loop_ticks"]`` the loop ticks each shard executed and
+    ``stats["shard_chunks"]`` the chunk-scans each shard began (its loop
+    ticks over ``chunk``, rounded up), both summed over runs.  On a single
+    device (or an unbatched state) this falls back to :class:`JitEngine`."""
 
     name = "sharded"
 
@@ -288,7 +311,8 @@ class ShardedEngine:
         self._ips = int(instrs_per_step)
         self.mesh = Mesh(np.array(devs), (HARTS,))
         self.sharding = NamedSharding(self.mesh, P(HARTS))
-        self.stats = {"place_bytes": 0, "shard_chunks": [0] * len(devs)}
+        self.stats = {"place_bytes": 0, "loop_ticks": [0] * len(devs),
+                      "shard_chunks": [0] * len(devs)}
 
     def _place(self, state):
         """``state`` padded to a device multiple and laid out on the mesh,
@@ -321,16 +345,21 @@ class ShardedEngine:
                 warnings.filterwarnings(
                     "ignore", message=".*[Dd]onat.*", category=UserWarning)
             b = int(state.counters.done.shape[0])
-            out, chunks = _run_sharded(
+            out, blocks = _run_sharded(
                 self._place(state), jnp.asarray(_n_chunks(max_ticks, chunk),
                                                 jnp.int32),
                 self.mesh, int(chunk), ips)
             if int(out.counters.done.shape[0]) != b:
                 out = jax.tree.map(lambda x: x[:b], out)
+            out = jax.block_until_ready(out)
+            ticks = [int(n) * _block(int(chunk), ips)
+                     for n in np.asarray(blocks)]
+            self.stats["loop_ticks"] = [
+                a + t for a, t in zip(self.stats["loop_ticks"], ticks)]
             self.stats["shard_chunks"] = [
-                a + int(c) for a, c in zip(self.stats["shard_chunks"],
-                                           np.asarray(chunks))]
-            return jax.block_until_ready(out)
+                a + -(-t // int(chunk))
+                for a, t in zip(self.stats["shard_chunks"], ticks)]
+            return out
 
 
 # ---------------------------------------------------------------------------

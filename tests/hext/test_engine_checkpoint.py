@@ -321,8 +321,10 @@ def test_sharded_engine_multi_device_matches_jit():
         # 8 rows of the 6-hart state were laid out
         row = sum(x.nbytes for x in jax.tree.leaves(fj.harts.unwrap())) // 6
         assert eng.stats['place_bytes'] == 8 * row, eng.stats
-        # the last shard holds the two padding harts: done from the start
+        # the last shard holds the two padding harts: done from the start;
+        # the others end inside their first 64-tick block
         assert eng.stats['shard_chunks'] == [1, 1, 1, 0], eng.stats
+        assert eng.stats['loop_ticks'] == [64, 64, 64, 0], eng.stats
         print('SHARDED-MULTI-OK')
     """)
     assert "SHARDED-MULTI-OK" in out
@@ -358,6 +360,7 @@ def test_sharded_engine_matrix_on_the_mesh_matches_jit_and_goldens():
             state = jax.device_put(base.harts.unwrap(), eng.sharding)
         fs = Fleet(state, base.specs, engine=eng)
         own = [0] * 4
+        ticks = [0] * 4
         for budget in (2 * CHUNK, 120000):
             with x64():
                 before = np.asarray(fs.harts.unwrap().counters.ticks)
@@ -366,8 +369,11 @@ def test_sharded_engine_matrix_on_the_mesh_matches_jit_and_goldens():
                 adv = np.asarray(fs.harts.unwrap().counters.ticks) - before
             for s in range(4):
                 own[s] += -(-int(adv[2 * s:2 * s + 2].max()) // CHUNK)
+                ticks[s] += -(-int(adv[2 * s:2 * s + 2].max()) // 64) * 64
         assert eng.stats["place_bytes"] == 0, eng.stats
         assert eng.stats["shard_chunks"] == own, (eng.stats, own)
+        assert eng.stats["loop_ticks"] == ticks, (eng.stats, ticks)
+        assert ticks != [CHUNK * c for c in own], ticks
         with x64():
             mem = fs.harts.unwrap().mem
             held = sorted(s.device.id for s in mem.addressable_shards)
@@ -381,7 +387,7 @@ def test_sharded_engine_matrix_on_the_mesh_matches_jit_and_goldens():
             want = ref[w.name]["guest" if g else "native"]
             assert {{k: got[k] for k in want}} == want, (w.name, g)
             assert got["exit_code"] == ref[w.name]["checksum"]
-        print("SHARDED-MATRIX-OK", own)
+        print("SHARDED-MATRIX-OK", own, ticks)
     """)
     assert "SHARDED-MATRIX-OK" in out
 
